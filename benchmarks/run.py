@@ -16,6 +16,7 @@ from typing import List
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))  # benchmarks pkg
 
+from repro.launch import compile_cache  # noqa: E402
 from benchmarks import (bench_kernels, bench_paper_fig2, bench_paper_fig3,
                         bench_paper_fig4, bench_roofline, bench_serving)
 
@@ -35,6 +36,7 @@ def main() -> None:
                     help="comma-separated suite names (default: all)")
     args = ap.parse_args()
     names = args.only.split(",") if args.only else list(SUITES)
+    compile_cache.enable()
 
     rows: List[str] = ["name,us_per_call,derived"]
     t0 = time.time()

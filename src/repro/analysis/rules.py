@@ -157,11 +157,10 @@ def _mutate_policy_sign() -> RuleResult:
 
 def _mutate_collective() -> RuleResult:
     if jax.device_count() >= 2:
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
         mesh = Mesh(np.asarray(jax.devices()[:2]), ("d",))
-        f = shard_map(lambda x: jax.lax.psum(x, "d"), mesh,
-                      in_specs=P("d"), out_specs=P())
+        f = jax.shard_map(lambda x: jax.lax.psum(x, "d"), mesh=mesh,
+                          in_specs=P("d"), out_specs=P())
         txt = jax.jit(f).lower(
             jax.ShapeDtypeStruct((2, 4), jnp.float32)).compile().as_text()
         src = "injected psum (shard_map over 2 devices)"

@@ -41,6 +41,7 @@ import math
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
+import jax.extend.core as jex_core
 import jax.numpy as jnp
 import numpy as np
 
@@ -247,7 +248,7 @@ class SignAnalysis:
                     aval=f"{getattr(val, 'dtype', '?')}{getattr(val, 'shape', '')}")
 
     def _read(self, env: Dict, v) -> Node:
-        if isinstance(v, jax.core.Literal):
+        if isinstance(v, jex_core.Literal):
             return Node(self._value_interval(v.val), "literal", aval=str(v.aval))
         return env[v]
 

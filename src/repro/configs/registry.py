@@ -1,4 +1,5 @@
-"""Architecture registry: ``get(name)`` -> module with FULL / SMOKE configs.
+"""Architecture registry: ``get_preset(name, preset)`` -> the arch's SMOKE,
+CHIP (where defined) or FULL config; ``get_smoke``/``get_full`` name two.
 
 Every config cites its source (paper / model card) per the assignment pool.
 ``--arch <id>`` in the launchers resolves through here.
@@ -32,13 +33,27 @@ def names(include_paper: bool = False) -> List[str]:
     return ns + [n for n in _ARCHS if n.startswith("paper-")] if include_paper else ns
 
 
-def get_full(name: str) -> ArchConfig:
-    return importlib.import_module(_ARCHS[name]).FULL
-
-
-def get_smoke(name: str) -> ArchConfig:
-    return importlib.import_module(_ARCHS[name]).SMOKE
+PRESETS = ("smoke", "chip", "full")
 
 
 def get_module(name: str):
     return importlib.import_module(_ARCHS[name])
+
+
+def get_preset(name: str, preset: str) -> ArchConfig:
+    """``preset``: ``smoke`` (CPU toy), ``chip`` (one chip's share at the
+    published widths, where the config defines one) or ``full``."""
+    if preset not in PRESETS:
+        raise ValueError(f"unknown preset {preset!r}: valid are {PRESETS}")
+    mod = get_module(name)
+    if not hasattr(mod, preset.upper()):
+        raise ValueError(f"{name} defines no {preset!r} preset")
+    return getattr(mod, preset.upper())
+
+
+def get_full(name: str) -> ArchConfig:
+    return get_preset(name, "full")
+
+
+def get_smoke(name: str) -> ArchConfig:
+    return get_preset(name, "smoke")

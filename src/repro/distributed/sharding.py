@@ -26,16 +26,8 @@ from repro.nn.module import map_with_path
 
 def abstract_mesh(axis_sizes: Sequence[int],
                   axis_names: Sequence[str]) -> AbstractMesh:
-    """Version-portable AbstractMesh constructor.
-
-    jax <= 0.4.35 took ``AbstractMesh(shape, axis_names)``; jax 0.4.36+
-    takes a single ``shape_tuple`` of (name, size) pairs.  All in-repo
-    device-free partition-rule checks go through this helper.
-    """
-    try:
-        return AbstractMesh(tuple(zip(axis_names, axis_sizes)))
-    except TypeError:  # older positional (shape, names) signature
-        return AbstractMesh(tuple(axis_sizes), tuple(axis_names))
+    """A device-free mesh for the partition-rule checks."""
+    return AbstractMesh(tuple(axis_sizes), tuple(axis_names))
 
 # Candidate trailing-dim specs per path regex (first match wins; within a
 # match, first divisible candidate wins).  "model" is the tensor axis;

@@ -58,7 +58,7 @@ def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
 
 def decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray, v_cache: jnp.ndarray,
                      pos, *, window: int = 0, bk: int = 256,
-                     interpret: bool = True) -> jnp.ndarray:
+                     interpret: bool = False) -> jnp.ndarray:
     """q: (B, Hq, D); caches: (B, C, Hkv, D); pos: scalar -> (B, Hq, Dv)."""
     B, Hq, D = q.shape
     C, Hkv = k_cache.shape[1], k_cache.shape[2]

@@ -72,7 +72,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 
 def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                     causal: bool = True, window: int = 0, bq: int = 128,
-                    bk: int = 128, interpret: bool = True) -> jnp.ndarray:
+                    bk: int = 128, interpret: bool = False) -> jnp.ndarray:
     """q: (B, S, Hq, D); k, v: (B, T, Hkv, D) -> (B, S, Hq, Dv)."""
     B, S, Hq, D = q.shape
     T, Hkv = k.shape[1], k.shape[2]

@@ -11,8 +11,8 @@ TPU legality: flat (N,) score vectors are reshaped to 2D (rows, 128) tiles
 (the VPU lane width; f32 tiles are (8, 128)), padded with "quiet" values
 (u = gamma - margin, so the padding neither triggers nor counts as a safety
 violation).  The [n_triggered, n_violations] counters accumulate across the
-sequential TPU grid in SMEM.  ``interpret=None`` auto-selects the compiled
-path on TPU and the interpreter everywhere else.
+sequential TPU grid in SMEM.  The kernel compiles for a TPU; the Pallas
+interpreter runs only when a caller passes ``interpret=True``.
 """
 from __future__ import annotations
 
@@ -51,15 +51,13 @@ def _combine_kernel(u_ref, v_ref, f_ref, fhat_ref, mask_ref, count_ref, *,
 
 def monitor_combine(u: jnp.ndarray, v: jnp.ndarray, f: jnp.ndarray, *,
                     s: float, threshold: float = 0.0, margin: float = 0.25,
-                    block: int = 1024, interpret: bool | None = None):
+                    block: int = 1024, interpret: bool = False):
     """u, v, f: (N,) flat score vectors -> (fhat, mask, counts[2]).
 
     ``block`` is the number of lanes processed per grid step (rounded to a
-    TPU-legal (rows, 128) tile).  ``interpret=None`` compiles on TPU and
-    falls back to the Pallas interpreter on CPU/GPU.
+    TPU-legal (rows, 128) tile).  ``interpret=True`` runs the Pallas
+    interpreter instead of compiling for a TPU.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     N = u.shape[0]
     rows = max(block // LANES, 1)
     if not interpret:

@@ -52,7 +52,7 @@ def _ssd_kernel(xdt_ref, la_ref, b_ref, c_ref, y_ref, h_ref, *, L: int):
 
 def ssd_scan(xdt: jnp.ndarray, la: jnp.ndarray, Bm: jnp.ndarray,
              Cm: jnp.ndarray, *, chunk: int = 128,
-             interpret: bool = True) -> jnp.ndarray:
+             interpret: bool = False) -> jnp.ndarray:
     """xdt: (B,S,H,P) pre-multiplied x*dt; la: (B,S,H) log-decay dt*A;
     Bm, Cm: (B,S,N).  Returns y: (B,S,H,P) (f32 accumulation)."""
     B, S, H, P = xdt.shape

@@ -89,7 +89,7 @@ def _compile(cfg, shape, mesh):
     # extra copies under donation+sharding and the cost model penalises it
     # (+12% memory term, +10 GiB args+temp) — so the dry-run measures the
     # undonated form.
-    with mesh:
+    with jax.set_mesh(mesh):
         return (jax.jit(step_fn, in_shardings=in_shardings)
                 .lower(*args).compile(), args)
 

@@ -12,7 +12,9 @@ Two engines:
     docs/api.md and docs/protocol.md).
 
 Run:  PYTHONPATH=src python -m repro.launch.serve --arch zamba2-7b \
-          --smoke --tokens 64 --batch 4
+          --tokens 64 --batch 4
+      PYTHONPATH=src python -m repro.launch.serve --arch granite-8b \
+          --preset chip --engine collab --batch 32 --tokens 32
       PYTHONPATH=src python -m repro.launch.serve --arch granite-8b \
           --engine collab --mode async --latency-ms 20 --max-staleness 8
 """
@@ -28,6 +30,7 @@ import numpy as np
 from repro.configs import registry
 from repro.core import decomposition as deco
 from repro.data import tokens as tok
+from repro.launch import compile_cache
 from repro.launch.steps import EDGE_CACHE_LEN, make_serve_step
 from repro.models import api as model_api
 from repro.training import checkpoint as ckpt
@@ -98,7 +101,9 @@ def run_collab(args, cfg, params) -> None:
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, choices=registry.names())
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--preset", choices=registry.PRESETS, default="smoke",
+                    help="smoke: CPU toy; chip: one chip's share at the "
+                         "published widths (where defined); full")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--tokens", type=int, default=64)
     ap.add_argument("--ckpt-dir", default=None)
@@ -133,7 +138,8 @@ def main() -> None:
         from repro.launch.server import _force_host_devices
         _force_host_devices(args.mesh)  # before the first jax computation
 
-    cfg = registry.get_smoke(args.arch) if args.smoke else registry.get_full(args.arch)
+    compile_cache.enable()
+    cfg = registry.get_preset(args.arch, args.preset)
     key = jax.random.PRNGKey(0)
     params = deco.init_collab_lm(key, cfg)
     if args.ckpt_dir:

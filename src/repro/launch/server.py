@@ -35,17 +35,50 @@ from repro.core import decomposition as deco
 from repro.training import checkpoint as ckpt
 
 
-def resolve_config(name: str, smoke: bool = True):
-    """Registry archs plus the paper-synthetic SERVING preset (the
-    bench_serving workload, which lives outside the registry)."""
+def resolve_config(name: str, preset: str = "smoke"):
+    """Registry archs at ``preset`` (``registry.PRESETS``) plus the
+    paper-synthetic SERVING preset (the bench_serving workload, which
+    lives outside the registry)."""
     if name == "paper-synthetic-serving":
         from repro.configs.paper_synthetic import SERVING
         return SERVING
-    return registry.get_smoke(name) if smoke else registry.get_full(name)
+    return registry.get_preset(name, preset)
 
 
 def config_names():
     return registry.names() + ["paper-synthetic-serving"]
+
+
+def check_chip_free(n_children: int = 1) -> None:
+    """Refuse to start correction-server processes that would fight over
+    an accelerator.
+
+    A chip belongs to one process at a time: a parent that has opened a
+    TPU backend holds every chip of the host, and each child started
+    without its own chip asks for all of them.  Children may start when
+    they run on the CPU (``JAX_PLATFORMS=cpu``, or the parent's own
+    backend is the CPU), or when the parent is off JAX and starts one.
+    Otherwise this raises; serve from one process instead (a
+    ``CorrectionServer`` on a thread, fleet ``backend="thread"``)."""
+    if os.environ.get("JAX_PLATFORMS") == "cpu":
+        return
+    from jax._src import xla_bridge
+    if xla_bridge.backends_are_initialized():
+        backend = jax.default_backend()
+        if backend == "cpu":
+            return
+        raise RuntimeError(
+            f"this process holds the {backend} devices, so a correction-"
+            "server child could not open them: run the server in this "
+            "process (a CorrectionServer on a thread, or fleet "
+            "backend='thread'), or run both on the CPU with "
+            "JAX_PLATFORMS=cpu")
+    if n_children > 1:
+        raise RuntimeError(
+            f"{n_children} correction-server processes would each open "
+            "every accelerator chip of this host: start one, serve the "
+            "replicas from one process (fleet backend='thread'), or run "
+            "them on the CPU with JAX_PLATFORMS=cpu")
 
 
 def spawn_subprocess(arch: str, *, uds: str, slots: int, max_len: int,
@@ -62,9 +95,11 @@ def spawn_subprocess(arch: str, *, uds: str, slots: int, max_len: int,
     (default 240 s — jax import on a loaded 2-core CI container can eat
     most of the old hardcoded 180 s).  ``wait=False`` returns the Popen
     immediately (the fleet supervisor ready-waits N servers in parallel
-    with ``wait_ready``)."""
+    with ``wait_ready``).  Refuses where the child would contend for an
+    accelerator (``check_chip_free``)."""
     import subprocess
 
+    check_chip_free()
     if timeout_s is None:
         timeout_s = float(os.environ.get("REPRO_SPAWN_DEADLINE_S", "240"))
     src = os.path.dirname(os.path.dirname(os.path.dirname(
@@ -117,7 +152,10 @@ def _force_host_devices(mesh: str) -> None:
 def main(argv=None) -> None:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--arch", required=True, choices=config_names())
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--preset", choices=registry.PRESETS, default="smoke",
+                    help="registry archs: smoke (CPU toy), chip (one "
+                         "chip's share at the published widths, where "
+                         "defined) or full")
     ap.add_argument("--uds", default=None, help="Unix-domain socket path")
     ap.add_argument("--host", default="127.0.0.1")
     ap.add_argument("--port", type=int, default=None,
@@ -162,7 +200,9 @@ def main(argv=None) -> None:
         # use), so setting XLA_FLAGS here still takes effect.
         _force_host_devices(args.mesh)
 
-    cfg = resolve_config(args.arch, args.smoke)
+    from repro.launch import compile_cache
+    compile_cache.enable()
+    cfg = resolve_config(args.arch, args.preset)
     params = deco.init_collab_lm(jax.random.PRNGKey(0), cfg)
     if args.ckpt_dir:
         _, params, _ = ckpt.load(args.ckpt_dir, params)

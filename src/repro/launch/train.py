@@ -8,7 +8,7 @@ by launch/dryrun.py).
 
 Run (CPU, reduced):
     PYTHONPATH=src python -m repro.launch.train --arch granite-8b \
-        --smoke --steps 50
+        --preset smoke --steps 50
 Run (pod):
     python -m repro.launch.train --arch qwen1.5-110b --mesh production
 """
@@ -24,6 +24,7 @@ from repro.configs import registry
 from repro.core import decomposition as deco
 from repro.data import tokens as tok
 from repro.distributed import sharding as shd
+from repro.launch import compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.launch.steps import make_train_step
 from repro.training import checkpoint as ckpt
@@ -34,8 +35,8 @@ from repro.training.schedule import warmup_cosine
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, choices=registry.names())
-    ap.add_argument("--smoke", action="store_true",
-                    help="use the reduced config (CPU)")
+    ap.add_argument("--preset", choices=registry.PRESETS, default="full",
+                    help="config preset: smoke (CPU toy), chip or full")
     ap.add_argument("--mesh", default="host",
                     choices=["host", "production", "multipod"])
     ap.add_argument("--steps", type=int, default=100)
@@ -46,7 +47,8 @@ def main() -> None:
     ap.add_argument("--ckpt-every", type=int, default=500)
     args = ap.parse_args()
 
-    cfg = registry.get_smoke(args.arch) if args.smoke else registry.get_full(args.arch)
+    compile_cache.enable()
+    cfg = registry.get_preset(args.arch, args.preset)
     mesh = {"host": make_host_mesh,
             "production": make_production_mesh,
             "multipod": lambda: make_production_mesh(multi_pod=True)}[args.mesh]()
@@ -57,7 +59,7 @@ def main() -> None:
     step_fn = make_train_step(cfg, opt)
 
     key = jax.random.PRNGKey(0)
-    with mesh:
+    with jax.set_mesh(mesh):
         params = deco.init_collab_lm(key, cfg)
         opt_state = opt.init(params)
         pshard = shd.param_shardings(params, mesh)

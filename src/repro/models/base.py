@@ -76,10 +76,7 @@ def _sp_mesh(cfg: ArchConfig, h):
     if not cfg.seq_parallel or h.ndim != 3:
         return None
     try:
-        mesh = jax.sharding.get_abstract_mesh()
-        if mesh is None or "model" not in getattr(mesh, "axis_names", ()):
-            from jax.interpreters import pxla
-            mesh = pxla.thread_resources.env.physical_mesh
+        mesh = jax.sharding.get_abstract_mesh()  # set by jax.set_mesh
         if "model" not in mesh.axis_names or mesh.shape["model"] <= 1:
             return None
         if h.shape[1] % mesh.shape["model"] != 0:

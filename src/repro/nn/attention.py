@@ -65,10 +65,7 @@ def _kv_time_shard(k: jnp.ndarray, v: jnp.ndarray):
     if getattr(_KV_SHARD, "mode", "none") != "time":
         return k, v
     try:
-        mesh = jax.sharding.get_abstract_mesh()
-        if mesh is None or "model" not in getattr(mesh, "axis_names", ()):
-            from jax.interpreters import pxla
-            mesh = pxla.thread_resources.env.physical_mesh
+        mesh = jax.sharding.get_abstract_mesh()  # set by jax.set_mesh
         m = mesh.shape.get("model", 1) if "model" in mesh.axis_names else 1
         if m <= 1 or k.shape[2] % m == 0 or k.shape[1] % m != 0:
             return k, v

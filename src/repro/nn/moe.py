@@ -14,7 +14,6 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.nn.module import Params, init_linear, linear, normal_init
@@ -154,22 +153,9 @@ def moe_apply(p: Params, x: jnp.ndarray, *, n_experts: int, top_k: int,
 
 
 def _current_mesh():
-    try:  # jax >= 0.5 public API; 0.4.3x keeps it under jax._src.mesh
-        get = jax.sharding.get_abstract_mesh
-    except AttributeError:
-        try:
-            from jax._src.mesh import get_abstract_mesh as get
-        except ImportError:
-            get = lambda: None
-    m = get()
-    if m is not None and getattr(m, "axis_names", None):
-        return m
-    try:  # legacy `with mesh:` context
-        from jax.interpreters import pxla
-        pm = pxla.thread_resources.env.physical_mesh
-        return pm if pm.axis_names else None
-    except Exception:
-        return None
+    """The mesh set by ``jax.set_mesh``, or None outside one."""
+    m = jax.sharding.get_abstract_mesh()
+    return m if m.axis_names else None
 
 
 def ep_applicable(n_experts: int) -> bool:
@@ -241,11 +227,11 @@ def moe_apply_ep(p: Params, x: jnp.ndarray, *, n_experts: int, top_k: int,
     rb = p["router"].get("b")
     if rb is None:
         rb = jnp.zeros((n_experts,), jnp.float32)
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(bspec, P(), P(), wspec, wspec, wdspec),
         out_specs=(bspec, P()),
-        check_rep=False)
+        check_vma=False)
     y, aux = fn(x, p["router"]["w"].astype(jnp.float32), rb,
                 p["w_gate"], p["w_up"], p["w_down"])
 

@@ -258,6 +258,9 @@ class StreamWorker(ServerWorker):
     def wait(self, req_id: int) -> List[CatchupReply]:
         out: List[CatchupReply] = []
         while not out or out[-1].req_id < req_id:
+            if not self._pending:
+                raise RuntimeError(f"stream worker: request {req_id} was "
+                                   "never dispatched")
             item = self._pending.popleft()
             if item[3] is None:
                 jax.block_until_ready(item[1])
@@ -1089,10 +1092,13 @@ class Dispatcher:
                              wall_dispatch=time.monotonic(),
                              step_t=int(t) if step_t is None else int(step_t))
         self._next_id += 1
+        # a dispatch that raises (a device out of memory) leaves nothing in
+        # flight, so closing the session drains only what was really sent
+        # and the caller sees the original error
+        self.worker.dispatch(req)
         self._inflight.append(req)
         if self.comms is not None:
             self.comms.record_dispatch(req.triggered)
-        self.worker.dispatch(req)
         return req
 
     def _arrived(self, replies: List[CatchupReply]) -> None:

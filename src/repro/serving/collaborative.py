@@ -267,17 +267,36 @@ class CollaborativeEngine:
         cache, last_hidden = jax.lax.fori_loop(
             0, n_rounds, round_body, (cache, last_hidden))
         v = self._v_head(params, last_hidden)
-        # fused combine (Pallas on TPU / oracle under "xla" impl): fhat,
-        # trigger mask and safety counters in one pass over the batch
+        # fused combine (Pallas on TPU, XLA elsewhere): fhat and the
+        # trigger mask in one pass over the batch
         if self.m.sigma == "sigmoid":
-            fhat_all, mask, _ = ops.monitor_combine(
-                u, v, u, s=self.m.s, threshold=self.m.threshold,
-                margin=self.m.trigger_margin)
+            fhat_all, mask = self._combine(u, v)
         else:
             corr = self.m.s * deco.sigma(v, self.m.sigma)
             fhat_all, mask = u - corr, triggered.astype(jnp.float32)
         fhat = jnp.where(triggered, fhat_all, u)
         return cache, v, fhat
+
+    def _combine(self, u, v):
+        """``ops.monitor_combine`` over the (B,) scores.  On a mesh-sharded
+        engine it runs per data shard under ``shard_map``: the combine is
+        elementwise, and a Pallas kernel is not partitioned automatically."""
+        def combine(u, v):
+            fhat, mask, _ = ops.monitor_combine(
+                u, v, u, s=self.m.s, threshold=self.m.threshold,
+                margin=self.m.trigger_margin)
+            return fhat, mask
+
+        if self.mesh is None:
+            return combine(u, v)
+        from jax.sharding import PartitionSpec as P
+        from repro.distributed.sharding import data_axes
+        daxes = data_axes(self.mesh)
+        spec = P(daxes if len(daxes) > 1 else daxes[0])
+        # check_vma off: the Pallas call's outputs carry no varying-axes
+        # annotation; every output is per-shard (out_specs) by construction
+        return jax.shard_map(combine, mesh=self.mesh, in_specs=(spec, spec),
+                             out_specs=(spec, spec), check_vma=False)(u, v)
 
     def _monitor_prologue(self, tokens_t):
         """The edge-only half of one step, shared by ``_step`` and

@@ -315,6 +315,11 @@ class FleetSupervisor:
             raise ValueError("subprocess backend needs arch=")
         if backend == "thread" and (cfg is None or params is None):
             raise ValueError("thread backend needs cfg= and params=")
+        if backend == "subprocess":
+            # one process per chip: N server processes must not each
+            # open every accelerator chip of the host
+            from repro.launch.server import check_chip_free
+            check_chip_free(n_servers)
         self.arch, self.cfg, self.params = arch, cfg, params
         self.backend = backend
         self.n_servers, self.slots, self.max_len = n_servers, slots, max_len

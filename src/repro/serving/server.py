@@ -826,6 +826,16 @@ class CorrectionServer:
         """Run until ``stop`` is set (or forever).  ``idle_exit_s``: exit
         once a session has existed and none remain for that long — test
         and bench hygiene for subprocess servers."""
+        try:
+            self._serve_loop(poll_s, stop, idle_exit_s)
+        except BaseException:
+            # a reactor that dies must not leave its clients waiting on
+            # open sockets: close them, as a crashed server process would
+            self.close()
+            raise
+
+    def _serve_loop(self, poll_s: float, stop: Optional[threading.Event],
+                    idle_exit_s: Optional[float]) -> None:
         idle_since: Optional[float] = None
         while stop is None or not stop.is_set():
             self.serve_tick(poll_s)

@@ -121,6 +121,25 @@ class TestLifecycle:
         assert s2.state == "open"
         s2.step(jnp.asarray(stream[:, 8]))
 
+    @pytest.mark.parametrize("transport", ["inproc", "stream"])
+    def test_failed_dispatch_surfaces_its_own_error(self, transport):
+        """A catch-up that raises at dispatch (a device out of memory)
+        reaches the caller as itself: closing the session must not drain
+        a request that was never sent."""
+        cfg, params, stream = _setup(threshold=-1e9)   # every step triggers
+        eng = CollaborativeEngine(params, cfg, batch=3, max_len=32)
+
+        def oom(*a):
+            raise RuntimeError("RESOURCE_EXHAUSTED: out of memory")
+
+        eng._catchup = oom
+        with pytest.raises(RuntimeError, match="RESOURCE_EXHAUSTED") as e:
+            with eng.session(SessionConfig(mode="async",
+                                           transport=transport)) as s:
+                s.step(jnp.asarray(stream[:, 0]))
+        assert e.value.__context__ is None, "close raised over the error"
+        assert eng._dispatcher is None or not eng._dispatcher.n_inflight
+
     def test_scan_sessions_are_offline_and_fixed(self):
         cfg, params, stream = _setup()
         eng = CollaborativeEngine(params, cfg, batch=3, max_len=32)

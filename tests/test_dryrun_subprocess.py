@@ -27,7 +27,8 @@ from repro.launch.dryrun import build_shardings
 from repro.launch.steps import step_and_specs, make_monitor_step, EDGE_CACHE_LEN
 from repro.models import api as model_api
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 out = {}
 for arch in ("granite-8b", "deepseek-v3-671b", "zamba2-7b"):
     cfg = registry.get_smoke(arch)
@@ -35,7 +36,7 @@ for arch in ("granite-8b", "deepseek-v3-671b", "zamba2-7b"):
                         ("decode", ShapeConfig("d", 64, 8, "decode"))):
         step, args = step_and_specs(cfg, shape)
         shards = build_shardings(args, cfg, shape, mesh)
-        with mesh:
+        with jax.set_mesh(mesh):
             c = jax.jit(step, in_shardings=shards).lower(*args).compile()
         out[f"{arch}/{kind}"] = "ok"
 
@@ -51,7 +52,7 @@ mstep = make_monitor_step(cfg)
 shards = (shd.param_shardings(params, mesh),
           shd.cache_shardings(edge_cache, mesh, 8, use_model=False),
           NamedSharding(mesh, P("data")), NamedSharding(mesh, P()))
-with mesh:
+with jax.set_mesh(mesh):
     txt = jax.jit(mstep, in_shardings=shards).lower(
         params, edge_cache, tokens, pos).compile().as_text()
 bad = []

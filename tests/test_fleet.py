@@ -570,3 +570,41 @@ class TestSubprocessFleet:
             stop.set()
             t.join(timeout=10)
             sup.close()
+
+
+class TestOneProcessPerChip:
+    """Server processes never fight over an accelerator: spawning refuses
+    where the parent holds the chip, or where several children would each
+    ask for every chip."""
+
+    def test_cpu_children_allowed(self, monkeypatch):
+        from repro.launch.server import check_chip_free
+        monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+        check_chip_free(4)
+
+    def test_parent_holding_tpu_refuses(self, monkeypatch):
+        from jax._src import xla_bridge
+        from repro.launch import server
+        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+        monkeypatch.setattr(xla_bridge, "backends_are_initialized",
+                            lambda: True)
+        monkeypatch.setattr(server.jax, "default_backend", lambda: "tpu")
+        with pytest.raises(RuntimeError, match="holds the tpu devices"):
+            server.check_chip_free()
+        with pytest.raises(RuntimeError, match="holds the tpu devices"):
+            server.spawn_subprocess("paper-synthetic-serving",
+                                    uds="/nonexistent.sock", slots=1,
+                                    max_len=8, ready_file="/nonexistent")
+
+    def test_parent_off_jax_starts_one(self, monkeypatch):
+        from jax._src import xla_bridge
+        from repro.launch.server import check_chip_free
+        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+        monkeypatch.setattr(xla_bridge, "backends_are_initialized",
+                            lambda: False)
+        check_chip_free(1)
+        with pytest.raises(RuntimeError, match="2 correction-server"):
+            check_chip_free(2)
+        with pytest.raises(RuntimeError, match="3 correction-server"):
+            FleetSupervisor("paper-synthetic-serving", n_servers=3,
+                            backend="subprocess")

@@ -1,5 +1,5 @@
 """Pallas kernel validation: shape/dtype sweeps vs the pure-jnp oracles in
-kernels/ref.py, interpret=True on CPU."""
+kernels/ref.py, in the Pallas interpreter (``interpret=True``) on CPU."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -36,7 +36,8 @@ class TestFlashAttention:
         q = rand((B, S, Hq, D), dtype, 1)
         k = rand((B, S, Hkv, D), dtype, 2)
         v = rand((B, S, Hkv, D), dtype, 3)
-        out = flash_attention(q, k, v, causal=True, window=window, bq=bq, bk=bk)
+        out = flash_attention(q, k, v, causal=True, window=window, bq=bq, bk=bk,
+                              interpret=True)
         ref = R.attention_ref(q, k, v, causal=True, window=window)
         np.testing.assert_allclose(np.asarray(out, np.float32),
                                    np.asarray(ref, np.float32),
@@ -61,7 +62,8 @@ class TestDecodeAttention:
         q = rand((B, Hq, D), dtype, 1)
         kc = rand((B, C, Hkv, D), dtype, 2)
         vc = rand((B, C, Hkv, D), dtype, 3)
-        out = decode_attention(q, kc, vc, pos, window=window, bk=bk)
+        out = decode_attention(q, kc, vc, pos, window=window, bk=bk,
+                               interpret=True)
         ref = R.decode_attention_ref(q, kc, vc, pos, window=window)
         np.testing.assert_allclose(np.asarray(out, np.float32),
                                    np.asarray(ref, np.float32),
@@ -82,7 +84,7 @@ class TestSSDScan:
         Cm = 0.5 * rand((B, S, N), jnp.float32, 4)
         xdt = x * dt[..., None]
         la = dt * A[None, None, :]
-        out = ssd_scan(xdt, la, Bm, Cm, chunk=chunk)
+        out = ssd_scan(xdt, la, Bm, Cm, chunk=chunk, interpret=True)
         ref = R.ssd_ref(xdt, la, Bm, Cm)
         np.testing.assert_allclose(out, ref, atol=5e-5, rtol=5e-4)
 
@@ -96,7 +98,8 @@ class TestMonitorCombine:
         k = jax.random.PRNGKey(seed)
         u, v, f = (jax.random.normal(jax.random.fold_in(k, i), (n,))
                    for i in range(3))
-        fh, m, c = monitor_combine(u, v, f, s=s, threshold=thr, block=256)
+        fh, m, c = monitor_combine(u, v, f, s=s, threshold=thr, block=256,
+                                   interpret=True)
         fr, mr, cr = R.monitor_combine_ref(u, v, f, s=s, threshold=thr)
         np.testing.assert_allclose(fh, fr, atol=1e-6)
         np.testing.assert_allclose(m, mr)
@@ -111,7 +114,7 @@ class TestOpsDispatch:
         a = ops.flash_attention(q, k, v)
         ops.set_impl("pallas_interpret")
         b = ops.flash_attention(q, k, v)
-        ops.set_impl("xla")
+        ops.set_impl(None)
         np.testing.assert_allclose(a, b, atol=2e-5, rtol=2e-5)
 
     @pytest.mark.parametrize("arch", ["granite-8b", "zamba2-7b",
@@ -132,6 +135,50 @@ class TestOpsDispatch:
             ops.set_impl("pallas_interpret")
             b = model_api.forward(params, cfg, batch)["logits"]
         finally:
-            ops.set_impl("xla")
+            ops.set_impl(None)
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=5e-4, rtol=5e-4)
+
+
+class TestKernelSelection:
+    """The platform picks ``monitor_combine``'s kernel; interpret mode runs
+    only on request, and a compiled kernel is never silently interpreted."""
+
+    def test_combine_follows_platform(self):
+        from repro.kernels import ops
+        want = "pallas" if jax.default_backend() == "tpu" else "xla"
+        assert ops.combine_impl() == want
+        assert ops.get_impl() == "xla"  # attention / SSD: XLA until A4
+
+    def test_pallas_off_tpu_raises(self):
+        from repro.kernels import ops
+        u = jnp.zeros((8,), jnp.float32)
+        try:
+            ops.set_impl("pallas")
+            if jax.default_backend() == "tpu":
+                ops.monitor_combine(u, u, u, s=0.2)
+            else:
+                with pytest.raises(RuntimeError, match="pallas_interpret"):
+                    ops.monitor_combine(u, u, u, s=0.2)
+        finally:
+            ops.set_impl(None)
+
+    def test_interpret_on_request_matches_xla(self):
+        from repro.kernels import ops
+        k = jax.random.PRNGKey(3)
+        u, v, f = (jax.random.normal(jax.random.fold_in(k, i), (300,))
+                   for i in range(3))
+        try:
+            ops.set_impl("pallas_interpret")
+            a = ops.monitor_combine(u, v, f, s=0.2)
+        finally:
+            ops.set_impl(None)
+        b = R.monitor_combine_ref(u, v, f, s=0.2)
+        for x, y in zip(a, b):
+            np.testing.assert_allclose(np.asarray(x), np.asarray(y),
+                                       atol=1e-6)
+
+    def test_unknown_impl_refused(self):
+        from repro.kernels import ops
+        with pytest.raises(ValueError):
+            ops.set_impl("cuda")

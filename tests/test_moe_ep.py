@@ -26,13 +26,9 @@ x = jax.random.normal(jax.random.fold_in(key, 1), (B, S, D), jnp.float32)
 
 y_ref, aux_ref = moe_apply(p, x, n_experts=E, top_k=K, compute_dtype=jnp.float32)
 
-try:
-    set_mesh = jax.sharding.set_mesh      # jax >= 0.5 public API
-except AttributeError:
-    set_mesh = lambda m: m                # legacy: Mesh is a context manager
-
-mesh = jax.make_mesh((2, 4), ("data", "model"))
-with set_mesh(mesh):
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
+with jax.set_mesh(mesh):
     assert ep_applicable(E), "ep must be applicable on 2x4 mesh with E=8"
     xs = jax.device_put(x, NamedSharding(mesh, P("data", None, None)))
     y_ep, aux_ep = jax.jit(lambda p, x: moe_apply_ep(
@@ -51,7 +47,7 @@ assert abs(float(aux_ref) - float(aux_ep)) < 5e-2, (aux_ref, aux_ep)
 def loss(p, x):
     y, aux = moe_apply_ep(p, x, n_experts=E, top_k=K, compute_dtype=jnp.float32)
     return jnp.sum(y ** 2) + aux
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     g = jax.jit(jax.grad(loss))(p, xs)
 for leaf in jax.tree.leaves(g):
     assert np.isfinite(np.asarray(leaf)).all()
@@ -62,7 +58,7 @@ E2 = 6
 p2 = init_moe(jax.random.fold_in(key, 7), D, F, E2)
 y2_ref, aux2_ref = moe_apply(p2, x, n_experts=E2, top_k=K,
                              compute_dtype=jnp.float32)
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     y2_ep, aux2_ep = jax.jit(lambda p, x: moe_apply_ep(
         p, x, n_experts=E2, top_k=K, compute_dtype=jnp.float32))(p2, xs)
 y2_ref, y2_ep = np.asarray(y2_ref), np.asarray(y2_ep)

@@ -545,6 +545,42 @@ class TestCoalescing:
             srv.close()
 
 
+class TestServerCrash:
+    def test_reactor_crash_closes_client_sockets(self):
+        """A server whose reactor dies (here: its replay raises) closes its
+        sessions, so the client fails fast with a WireError instead of
+        waiting on an open socket forever."""
+        from repro.serving.server import CorrectionServer
+        cfg = _cfg(threshold=-1e9)   # every step triggers
+        params = deco.init_collab_lm(KEY, cfg)
+        srv = CorrectionServer(cfg, params, slots=2, max_len=16,
+                               uds=_uds_path("crash"))
+
+        def boom(group):
+            raise RuntimeError("replay failed")
+
+        srv._replay = boom
+        th = threading.Thread(target=srv.serve_forever, daemon=True)
+        th.start()
+        stream = next(tok.lm_batches(0, cfg, 2, 4))["tokens"]
+        eng = CollaborativeEngine(params, cfg, batch=2, max_len=16)
+        caught = []
+
+        def client():
+            try:
+                run_wire(eng, stream, address=srv.address, max_staleness=0)
+            except Exception as e:  # noqa: BLE001 - recorded for the assert
+                caught.append(e)
+
+        c = threading.Thread(target=client, daemon=True)
+        c.start()
+        c.join(timeout=120)
+        th.join(timeout=10)
+        assert not c.is_alive(), "client hung on a dead server"
+        assert not th.is_alive()
+        assert caught and isinstance(caught[0], wire.WireError), caught
+
+
 class TestConnectHello:
     """Regression: the connect/handshake retry loop used to treat a
     refused handshake and a mid-handshake EOF identically — now a
