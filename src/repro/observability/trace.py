@@ -8,7 +8,8 @@ that claim gets a measurement.  One ``Tracer`` rides a serving session
 critical path crosses:
 
     edge track    edge.decode, edge.trigger, edge.dispatch, edge.merge,
-                  edge.catchup (sync), edge.stall, scan.run
+                  edge.catchup (sync; children catchup.dispatch and
+                  catchup.wait), session.policy, edge.stall, scan.run
     wire track    wire.encode (serialize), wire.request (dispatch ->
                   reply), wire.socket (derived: RTT minus the server's
                   reported durations)
@@ -26,13 +27,23 @@ exactly that.
 
 Cost discipline: the tracer is pay-for-what-you-use.  Sessions default
 to ``trace=False`` and every instrumentation site in the engine /
-dispatcher / worker is guarded by a single ``if tracer is not None``
-flag check — the disabled path never allocates a span, never reads the
-clock for tracing, and never touches this module (asserted by the
-overhead guard in tests/test_observability.py).  Enabled, a span is one
-``time.monotonic()`` pair plus an append into a bounded deque; nothing
-here touches jax, so instrumentation can never introduce host transfers
-or retraces (``tools/check_static.py --strict`` stays green).
+dispatcher / worker goes through ``span(tracer, ...)``, which is a
+single ``tracer is None`` check returning a shared no-op span when
+disabled — the disabled path never allocates a span, never reads the
+clock for tracing, and never constructs a profiler annotation (asserted
+by the overhead guard in tests/test_observability.py).
+
+Two sinks, one span: a live span (``Tracer.span``) records its ring
+entry on the monotonic clock exactly as ``done`` does, and while it is
+open it also holds a ``jax.profiler.TraceAnnotation`` (a TraceMe) with
+the span's args as metadata.  When a profiler trace is active the span
+therefore lands on the profiler's host plane, on the device trace's
+clock, beside the device's operations; when none is active the
+annotation costs a few microseconds and records nothing.  Synthesized
+spans (``add``: ``wire.request``, ``wire.socket``, ``server.*``) are
+placed after the fact and reach the ring only.  The annotation touches
+no device array, so instrumentation introduces no host transfer and no
+retrace (``tools/check_static.py --strict`` stays green).
 
 Synthesized-span placement: the server reports DURATIONS only
 (queue-wait and replay compute).  The client anchors them backwards from
@@ -49,6 +60,8 @@ import os
 import time
 from collections import deque
 from typing import Any, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 # stable track -> Chrome tid mapping (one "process" per tracer)
 TRACKS = ("edge", "wire", "server")
@@ -118,9 +131,13 @@ class Tracer:
         self._spans.append(Span(name, cat, ts, max(float(dur), 0.0),
                                 track, args))
 
-    def instant(self, name: str, cat: str = "mark", *,
-                track: str = "edge", **args: Any) -> None:
-        self.add(name, cat, time.monotonic(), 0.0, track=track, **args)
+    def span(self, name: str, cat: str, *, track: str = "edge",
+             **args: Any) -> "LiveSpan":
+        """A context manager for a span around live work: the ring span
+        (as ``done`` records it, from entry to exit) plus a profiler
+        ``TraceAnnotation`` held while it is open.  Args known only
+        later are added with ``set`` on the object ``with`` yields."""
+        return LiveSpan(self, name, cat, track, args)
 
     # -- inspection ----------------------------------------------------------
     def spans(self) -> List[Span]:
@@ -169,6 +186,65 @@ class Tracer:
         with open(path, "w") as fh:
             json.dump(self.to_chrome(), fh)
         return len(self._spans)
+
+
+class LiveSpan:
+    """One open span of ``Tracer.span``.  Not recorded when the body
+    raises, as ``done`` is not called then."""
+
+    __slots__ = ("_tracer", "_name", "_cat", "_track", "_args", "_t0",
+                 "_tm")
+
+    def __init__(self, tracer: Tracer, name: str, cat: str, track: str,
+                 args: Dict[str, Any]):
+        self._tracer, self._name, self._cat = tracer, name, cat
+        self._track, self._args = track, args
+
+    def __enter__(self) -> "LiveSpan":
+        self._tm = TraceAnnotation(self._name, **self._args)
+        self._tm.__enter__()
+        self._t0 = time.monotonic()
+        return self
+
+    def set(self, **args: Any) -> None:
+        """Add args to the open span (ring entry and annotation both)."""
+        self._args.update(args)
+        self._tm.set_metadata(**args)
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        if exc_type is None:
+            self._tracer.done(self._name, self._cat, self._t0,
+                              track=self._track, **self._args)
+        self._tm.__exit__(exc_type, exc, tb)
+
+
+class _NoSpan:
+    """The disabled path's span: enters, takes args and exits doing
+    nothing."""
+
+    __slots__ = ()
+
+    def __enter__(self) -> "_NoSpan":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        return None
+
+    def set(self, **args: Any) -> None:
+        return None
+
+
+NO_SPAN = _NoSpan()
+
+
+def span(tracer: Optional[Tracer], name: str, cat: str, *,
+         track: str = "edge", **args: Any):
+    """``tracer.span(...)``, or the shared no-op span when tracing is
+    off (``tracer is None``): the one flag check of an instrumentation
+    site."""
+    if tracer is None:
+        return NO_SPAN
+    return tracer.span(name, cat, track=track, **args)
 
 
 def validate_chrome_trace(obj: Any) -> int:

@@ -67,6 +67,7 @@ from typing import Any, Dict, Hashable, Iterable, Iterator, Optional, Tuple, Uni
 
 import numpy as np
 
+from repro.observability import span
 from repro.serving.async_rpc import TRANSPORTS
 
 MODES = ("sync", "scan", "async")
@@ -501,18 +502,21 @@ class MonitorSession:
         self._ensure_open()
         full = self._expand(tokens)
         eng = self._engine
+        tr, step = eng._tracer, eng.t
         if self._policy is not None:
             # thresholds are data, not structure: writing the vector
             # never retraces a jitted path (recompile-guard-tested)
-            eng._thr_eff = np.asarray(self._policy.step_thresholds(),
-                                      np.float32)
+            with span(tr, "session.policy", "edge", step=step):
+                eng._thr_eff = np.asarray(self._policy.step_thresholds(),
+                                          np.float32)
         if self.config.needs_worker:
             r = eng._step_async(full)
         else:
             r = eng._step(full)
         if self._policy is not None:
-            self._policy.update(r["u"], r["fhat"], r["triggered"],
-                                eng.active.copy(), eng.comms)
+            with span(tr, "session.policy", "edge", step=step):
+                self._policy.update(r["u"], r["fhat"], r["triggered"],
+                                    eng.active.copy(), eng.comms)
         return self._narrow(r)
 
     def stream(self, token_iter: Iterable) -> Iterator[Dict[str, Any]]:

@@ -94,6 +94,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.observability import span
+
 TRANSPORTS = ("inproc", "stream", "thread", "mock_remote", "wire", "shm")
 
 
@@ -733,16 +735,15 @@ class SocketWorker(ServerWorker):
         trig = np.asarray(req.triggered, bool)
         pos = np.asarray(req.server_pos, np.int32)
         n_tok = int(np.where(trig, int(req.t) + 1 - pos, 0).sum())
-        t_enc = time.monotonic()
-        buf = self._wire.encode_request(
-            req.req_id, int(req.t), trig, pos,
-            np.asarray(req.u, np.float32), hist)
-        t_send = time.monotonic()
+        with span(self._tracer, "wire.encode", "wire", track="wire",
+                  req_id=req.req_id, tokens=n_tok) as sp:
+            t_enc = time.monotonic()
+            buf = self._wire.encode_request(
+                req.req_id, int(req.t), trig, pos,
+                np.asarray(req.u, np.float32), hist)
+            t_send = time.monotonic()
+            sp.set(bytes=len(buf))
         self._dispatch_wall[req.req_id] = (t_send, t_send - t_enc)
-        if self._tracer is not None:
-            self._tracer.add("wire.encode", "wire", t_enc, t_send - t_enc,
-                             track="wire", req_id=req.req_id,
-                             bytes=len(buf), tokens=n_tok)
         self._flights.append(_Flight(req.req_id, False, buf, int(req.t),
                                      trig.copy(), n_tok))
         try:
@@ -1118,14 +1119,13 @@ class Dispatcher:
         self._arrived(self.worker.poll())
         while (self._inflight
                and now_t - self._inflight[0].step_t >= self.max_staleness):
-            t0 = time.monotonic()
             head = self._inflight[0].req_id
-            replies = self.worker.wait(head)
-            if self.comms is not None:
-                self.comms.record_stall(time.monotonic() - t0)
-            if self.tracer is not None:
-                self.tracer.done("edge.stall", "edge", t0,
-                                 req_id=head, step=now_t)
+            with span(self.tracer, "edge.stall", "edge", req_id=head,
+                      step=now_t):
+                t0 = time.monotonic()
+                replies = self.worker.wait(head)
+                if self.comms is not None:
+                    self.comms.record_stall(time.monotonic() - t0)
             self._arrived(replies)
         min_age = 1 if self.max_staleness > 0 else 0
         out: List[CatchupReply] = []
@@ -1147,12 +1147,11 @@ class Dispatcher:
         worker.
         """
         if self._inflight:
-            t0 = time.monotonic()
-            self._arrived(self.worker.wait(self._inflight[-1].req_id))
-            if self.comms is not None:
-                self.comms.record_stall(time.monotonic() - t0)
-            if self.tracer is not None:
-                self.tracer.done("edge.stall", "edge", t0, drain=True)
+            with span(self.tracer, "edge.stall", "edge", drain=True):
+                t0 = time.monotonic()
+                self._arrived(self.worker.wait(self._inflight[-1].req_id))
+                if self.comms is not None:
+                    self.comms.record_stall(time.monotonic() - t0)
         out = list(self._held)
         self._held.clear()
         if self.comms is not None:

@@ -98,7 +98,8 @@ from repro.core import decomposition as deco
 from repro.core.gating import CommsMeter, compact_correction
 from repro.kernels import ops
 from repro.nn.module import linear
-from repro.observability import MetricsRegistry
+from repro.observability import MetricsRegistry, span
+from repro.observability.trace import NO_SPAN
 from repro.serving.engine import ServeEngine, zero_cache_rows
 
 # payload: one token id (4B) + edge score (4B) per shipped token
@@ -148,7 +149,9 @@ class CollaborativeEngine:
         # MonitorSession.metrics() snapshots it.  The span tracer is OFF
         # by default (None): SessionConfig(trace=True) installs one for
         # the session's lifetime, and every instrumentation site below is
-        # a single `is not None` check when disabled.
+        # a single `is None` check when disabled (observability.span).
+        # The sync catch-up counts its calls, rounds and backlog tokens
+        # replayed here, traced or not (a round decodes all B rows).
         self.metrics = MetricsRegistry()
         self._tracer = None
         self._dispatcher = None
@@ -246,11 +249,17 @@ class CollaborativeEngine:
         a scalar (uniform pool) or a (B,) vector of per-stream end
         positions (ragged slot pool / server-side coalescing) — the round
         mask ``pos <= t`` is elementwise either way.
+
+        Named scopes (``jax.named_scope``: op metadata only, numerics
+        untouched) split this one program in a device trace: the replay
+        loop's body is ``catchup.round`` (its cache write-back
+        ``decode.merge``), then ``catchup.v_head`` and ``catchup.combine``.
         """
         B = triggered.shape[0]
         step_at = self.server.get_step_at(with_logits=False)
         n_rounds = jnp.max(jnp.where(triggered, t + 1 - server_pos, 0))
 
+        @jax.named_scope("catchup.round")
         def round_body(r, carry):
             cache, last_hidden = carry
             pos = (server_pos + r).astype(jnp.int32)
@@ -266,15 +275,17 @@ class CollaborativeEngine:
         last_hidden = jnp.zeros((B, self.cfg.d_model), jnp.float32)
         cache, last_hidden = jax.lax.fori_loop(
             0, n_rounds, round_body, (cache, last_hidden))
-        v = self._v_head(params, last_hidden)
+        with jax.named_scope("catchup.v_head"):
+            v = self._v_head(params, last_hidden)
         # fused combine (Pallas on TPU, XLA elsewhere): fhat and the
         # trigger mask in one pass over the batch
-        if self.m.sigma == "sigmoid":
-            fhat_all, mask = self._combine(u, v)
-        else:
-            corr = self.m.s * deco.sigma(v, self.m.sigma)
-            fhat_all, mask = u - corr, triggered.astype(jnp.float32)
-        fhat = jnp.where(triggered, fhat_all, u)
+        with jax.named_scope("catchup.combine"):
+            if self.m.sigma == "sigmoid":
+                fhat_all, mask = self._combine(u, v)
+            else:
+                corr = self.m.s * deco.sigma(v, self.m.sigma)
+                fhat_all, mask = u - corr, triggered.astype(jnp.float32)
+            fhat = jnp.where(triggered, fhat_all, u)
         return cache, v, fhat
 
     def _combine(self, u, v):
@@ -311,34 +322,30 @@ class CollaborativeEngine:
         if (pos[active] >= self.max_len).any():
             raise ValueError(f"stream longer than max_len={self.max_len}")
         tr = self._tracer
-        t0 = tr.clock() if tr is not None else 0.0
-        tokens_t = jnp.asarray(tokens_t)
-        act_j = jnp.asarray(active)
-        self._history = self._record_at(
-            self._history, tokens_t, jnp.asarray(pos, jnp.int32), act_j)
-        # cohort decode: active slots sharing a position advance in one
-        # dense masked decode — per-row bitwise identical to the plain
-        # batched decode, so a uniform pool reproduces the fixed-batch
-        # path bit-for-bit and churn survivors match a fixed-batch run
-        u = None
-        for p in sorted(set(pos[active].tolist())):
-            mask = active & (pos == p)
-            _, hidden = self.edge.decode_masked(tokens_t, int(p),
-                                                jnp.asarray(mask))
-            u_p = self._u_head(self.params, hidden)  # (B,) device array
-            u = u_p if u is None else jnp.where(jnp.asarray(mask), u_p, u)
-        if not active.all():
-            u = jnp.where(act_j, u, 0.0)
-        if tr is not None:
-            tr.done("edge.decode", "edge", t0, step=self.t)
-            t1 = tr.clock()
-        # per-stream effective thresholds (policy-driven; seeded at the
-        # calibrated scalar, so the no-policy compare is bit-identical)
-        triggered = (np.asarray(u) > self._thr_eff) & active
-        if tr is not None:
-            # the sync point: host readback of the trigger mask
-            tr.done("edge.trigger", "edge", t1, step=self.t,
-                    n_triggered=int(triggered.sum()))
+        with span(tr, "edge.decode", "edge", step=self.t):
+            tokens_t = jnp.asarray(tokens_t)
+            act_j = jnp.asarray(active)
+            self._history = self._record_at(
+                self._history, tokens_t, jnp.asarray(pos, jnp.int32), act_j)
+            # cohort decode: active slots sharing a position advance in one
+            # dense masked decode — per-row bitwise identical to the plain
+            # batched decode, so a uniform pool reproduces the fixed-batch
+            # path bit-for-bit and churn survivors match a fixed-batch run
+            u = None
+            for p in sorted(set(pos[active].tolist())):
+                mask = active & (pos == p)
+                _, hidden = self.edge.decode_masked(tokens_t, int(p),
+                                                    jnp.asarray(mask))
+                u_p = self._u_head(self.params, hidden)  # (B,) device array
+                u = u_p if u is None else jnp.where(jnp.asarray(mask), u_p, u)
+            if not active.all():
+                u = jnp.where(act_j, u, 0.0)
+        # the sync point: host readback of u for the trigger compare
+        with span(tr, "edge.trigger", "edge", step=self.t) as sp:
+            # per-stream effective thresholds (policy-driven; seeded at the
+            # calibrated scalar, so the no-policy compare is bit-identical)
+            triggered = (np.asarray(u) > self._thr_eff) & active
+            sp.set(n_triggered=int(triggered.sum()))
         return u, triggered
 
     def _check_not_detached(self) -> None:
@@ -365,24 +372,32 @@ class CollaborativeEngine:
         fhat = np.asarray(u).copy()
         if triggered.any():
             tr = self._tracer
-            t0 = tr.clock() if tr is not None else 0.0
-            uniform = active.all() and (t_vec == t_vec[0]).all()
-            # uniform pools pass the scalar t (the original compiled
-            # program); ragged pools pass per-slot end positions
-            t_arg = (jnp.asarray(int(t_vec[0]), jnp.int32) if uniform
-                     else jnp.asarray(t_vec, jnp.int32))
-            # each triggered stream ships ITS backlog; others untouched
-            cache, v, fhat_j = self._catchup(
-                self.params, self.server.cache, self._history,
-                jnp.asarray(self.server_pos, jnp.int32), t_arg,
-                jnp.asarray(triggered), u)
-            self.server.cache = cache
-            fhat = np.asarray(fhat_j)
-            if tr is not None:
-                # the sync path BLOCKS on the server here
-                tr.done("edge.catchup", "edge", t0, step=self.t,
-                        n_triggered=int(triggered.sum()))
+            # each triggered stream ships ITS backlog; others untouched.
+            # The catch-up runs rounds to the longest backlog (its loop's
+            # trip count), each round decoding all B rows.
             shipped = np.where(triggered, t_vec + 1 - self.server_pos, 0)
+            rounds, replayed = int(shipped.max()), int(shipped.sum())
+            with span(tr, "edge.catchup", "edge", step=self.t,
+                      n_triggered=int(triggered.sum()), rounds=rounds,
+                      rows=B, replayed=replayed):
+                with span(tr, "catchup.dispatch", "edge", step=self.t):
+                    uniform = active.all() and (t_vec == t_vec[0]).all()
+                    # uniform pools pass the scalar t (the original
+                    # compiled program); ragged pools pass per-slot end
+                    # positions
+                    t_arg = (jnp.asarray(int(t_vec[0]), jnp.int32)
+                             if uniform else jnp.asarray(t_vec, jnp.int32))
+                    cache, v, fhat_j = self._catchup(
+                        self.params, self.server.cache, self._history,
+                        jnp.asarray(self.server_pos, jnp.int32), t_arg,
+                        jnp.asarray(triggered), u)
+                    self.server.cache = cache
+                # the sync path BLOCKS on the server here
+                with span(tr, "catchup.wait", "edge", step=self.t):
+                    fhat = np.asarray(fhat_j)
+            self.metrics.inc("catchup.calls")
+            self.metrics.inc("catchup.rounds", rounds)
+            self.metrics.inc("catchup.replayed", replayed)
             self.comms.update_per_stream(shipped, active.astype(np.int64))
             self.server_pos = np.where(triggered, t_vec + 1, self.server_pos)
             self.server.pos = int(self.server_pos.max())
@@ -463,60 +478,63 @@ class CollaborativeEngine:
         # merges this step's own reply below
         tr = self._tracer
         if triggered.any():
-            t0 = tr.clock() if tr is not None else 0.0
-            shipped = np.where(triggered, t_vec + 1 - self._dispatch_pos, 0)
-            # one request per same-position cohort, so every request keeps
-            # the scalar-t backlog/wire semantics (a uniform pool is the
-            # single-request special case, bit-identical to before)
-            # cork the socket workers around the cohort fan-out: N
-            # same-tick requests leave in ONE transmit (the client half
-            # of wire micro-batching; a no-op for local transports)
-            worker = self._worker
-            corked = hasattr(worker, "cork")
-            if corked:
-                worker.cork()
-            try:
-                for p in sorted(set(t_vec[triggered].tolist())):
-                    mask_p = triggered & (t_vec == p)
-                    self._dispatcher.dispatch(
-                        t=int(p), triggered=mask_p,
-                        server_pos=self._dispatch_pos, history=self._history,
-                        u=u, step_t=self.t)
-            finally:
+            with span(tr, "edge.dispatch", "edge", step=self.t,
+                      n_triggered=int(triggered.sum())):
+                shipped = np.where(triggered, t_vec + 1 - self._dispatch_pos,
+                                   0)
+                # one request per same-position cohort, so every request
+                # keeps the scalar-t backlog/wire semantics (a uniform
+                # pool is the single-request special case, bit-identical
+                # to before); cork the socket workers around the cohort
+                # fan-out: N same-tick requests leave in ONE transmit (the
+                # client half of wire micro-batching; a no-op for local
+                # transports)
+                worker = self._worker
+                corked = hasattr(worker, "cork")
                 if corked:
-                    worker.uncork()
-            self.comms.update_per_stream(shipped, active.astype(np.int64))
-            self._dispatch_pos = np.where(triggered, t_vec + 1,
-                                          self._dispatch_pos)
-            if tr is not None:
-                tr.done("edge.dispatch", "edge", t0, step=self.t,
-                        n_triggered=int(triggered.sum()))
+                    worker.cork()
+                try:
+                    for p in sorted(set(t_vec[triggered].tolist())):
+                        mask_p = triggered & (t_vec == p)
+                        self._dispatcher.dispatch(
+                            t=int(p), triggered=mask_p,
+                            server_pos=self._dispatch_pos,
+                            history=self._history, u=u, step_t=self.t)
+                finally:
+                    if corked:
+                        worker.uncork()
+                self.comms.update_per_stream(shipped,
+                                             active.astype(np.int64))
+                self._dispatch_pos = np.where(triggered, t_vec + 1,
+                                              self._dispatch_pos)
         else:
             self.comms.update_per_stream(np.zeros(B, np.int64),
                                          active.astype(np.int64))
         fhat = u_np.copy()
-        t_merge = tr.clock() if tr is not None else 0.0
-        n_merged = 0
-        for r in self._dispatcher.collect(self.t):
-            # churn drains before rewriting membership, so a reply's mask
-            # can only reference still-attached slots; the `live` gate is
-            # defensive against both
-            live = r.triggered & self.active
-            if r.step_t == self.t:
-                # same-step merge (sync fallback): the fused fhat computed
-                # from this step's u — bit-identical to ``_step``
-                fhat = np.where(live, r.fhat, fhat)
-            else:
-                # late merge: the stale corrector applied to TODAY's u.
-                # corr >= 0, so fhat <= u — staleness can only keep a
-                # warning raised, never suppress one (safety semantics)
-                corr = np.asarray(m.s * deco.sigma(jnp.asarray(r.v), m.sigma))
-                fhat = np.where(live, u_np - corr, fhat)
-            self.server_pos = np.where(live, r.t + 1, self.server_pos)
-            n_merged += 1
-        if tr is not None and n_merged:
-            tr.done("edge.merge", "edge", t_merge, step=self.t,
-                    n_replies=n_merged)
+        # waiting for a reply past the staleness window is edge.stall,
+        # inside collect; edge.merge covers the merge of what arrived
+        replies = self._dispatcher.collect(self.t)
+        with (span(tr, "edge.merge", "edge", step=self.t,
+                   n_replies=len(replies)) if replies else NO_SPAN):
+            for r in replies:
+                # churn drains before rewriting membership, so a reply's
+                # mask can only reference still-attached slots; the
+                # `live` gate is defensive against both
+                live = r.triggered & self.active
+                if r.step_t == self.t:
+                    # same-step merge (sync fallback): the fused fhat
+                    # computed from this step's u — bit-identical to
+                    # ``_step``
+                    fhat = np.where(live, r.fhat, fhat)
+                else:
+                    # late merge: the stale corrector applied to TODAY's
+                    # u.  corr >= 0, so fhat <= u — staleness can only
+                    # keep a warning raised, never suppress one (safety
+                    # semantics)
+                    corr = np.asarray(m.s * deco.sigma(jnp.asarray(r.v),
+                                                       m.sigma))
+                    fhat = np.where(live, u_np - corr, fhat)
+                self.server_pos = np.where(live, r.t + 1, self.server_pos)
         self.edge_pos = t_vec + active
         self.t += 1
         return {"u": u_np, "fhat": fhat, "triggered": triggered}
@@ -661,18 +679,16 @@ class CollaborativeEngine:
         B, S = tokens.shape[0], tokens.shape[1]
         if S > self.max_len:
             raise ValueError(f"stream longer than max_len={self.max_len}")
-        tr = self._tracer
-        t0 = tr.clock() if tr is not None else 0.0
-        if B == self.batch:
-            thr_eff = jnp.asarray(self._thr_eff)
-        else:  # narrower offline trace: calibrated point for every row
-            thr_eff = jnp.full((B,), np.float32(self.m.threshold -
-                                                self.m.trigger_margin),
-                               jnp.float32)
-        u, fhat, trig, served = self._scan(self.params, tokens, thr_eff)
-        trig_np = np.asarray(trig)
-        if tr is not None:
-            tr.done("scan.run", "edge", t0, batch=int(B), steps=int(S))
+        with span(self._tracer, "scan.run", "edge", batch=int(B),
+                  steps=int(S)):
+            if B == self.batch:
+                thr_eff = jnp.asarray(self._thr_eff)
+            else:  # narrower offline trace: calibrated point for every row
+                thr_eff = jnp.full((B,), np.float32(self.m.threshold -
+                                                    self.m.trigger_margin),
+                                   jnp.float32)
+            u, fhat, trig, served = self._scan(self.params, tokens, thr_eff)
+            trig_np = np.asarray(trig)
         comms = CommsMeter(bytes_per_request=TOKEN_BYTES, n_streams=B)
         any_trig = trig_np.any(axis=1)
         last = np.where(any_trig, S - 1 - np.argmax(trig_np[:, ::-1], axis=1), -1)

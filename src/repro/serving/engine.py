@@ -140,7 +140,8 @@ def make_step_at(cfg: ArchConfig, axes, *, with_logits: bool = True):
             shape[ax] = B
             return jnp.where(jnp.reshape(active, shape), new, old)
 
-        cache = jax.tree.map(merge, new_cache, cache, axes)
+        with jax.named_scope("decode.merge"):
+            cache = jax.tree.map(merge, new_cache, cache, axes)
         return logits, hidden, cache
 
     return step_at

@@ -1,11 +1,15 @@
-"""Observability subsystem: span tracer ring/export/schema, the unified
-metrics registry, histogram percentile edge cases, the protocol-v4 REPLY
-timing payload (v3 compatibility both ways), and the load-bearing
-contract that tracing is FREE when off and INVISIBLE when on — traced
-sessions produce bitwise-identical protocol outputs on every execution
-path (sync / scan / async / wire)."""
+"""Observability subsystem: span tracer ring/export/schema, live spans
+on the profiler's host plane, the unified metrics registry, histogram
+percentile edge cases, the protocol-v4 REPLY timing payload (v3
+compatibility both ways), the sync catch-up's counts and the named scopes
+that split its compiled program, and the load-bearing contract that
+tracing is FREE when off and INVISIBLE when on — traced sessions produce
+bitwise-identical protocol outputs on every execution path (sync / scan /
+async / wire)."""
+import glob
 import json
 import os
+import re
 import struct
 import tempfile
 import threading
@@ -21,8 +25,10 @@ from repro.data import tokens as tok
 from repro.observability import (MetricsRegistry, Tracer, breakdown,
                                  breakdown_table, flatten, load_trace,
                                  validate_chrome_trace)
+from repro.observability import trace as trace_mod
 from repro.serving import SessionConfig, TransportSpec, wire
-from repro.serving.collaborative import CollaborativeEngine
+from repro.serving.collaborative import TOKEN_BYTES, CollaborativeEngine
+from repro.serving.policy import FixedPolicy
 from repro.serving.tracker import Histogram, InMemoryTracker
 
 KEY = jax.random.PRNGKey(0)
@@ -50,6 +56,58 @@ class TestTracer:
         assert [s.name for s in spans] == ["edge.decode", "server.queue"]
         assert spans[0].dur >= 0 and spans[0].args["step"] == 3
         assert spans[1].dur == 0.0, "negative durations clamp to zero"
+
+    def test_span_records_as_done(self):
+        """A live span's ring entry is the one ``done`` records: name,
+        category, track and args (late ones from ``set`` included), from
+        entry to exit; a body that raises records nothing."""
+        tr = Tracer()
+        tr.done("edge.catchup", "edge", tr.clock(), track="wire", step=3)
+        t_in = tr.clock()
+        with tr.span("edge.catchup", "edge", track="wire", step=3) as sp:
+            sp.set(rounds=2)
+        t_out = tr.clock()
+        by_done, by_span = tr.spans()
+        for a in ("name", "cat", "track"):
+            assert getattr(by_span, a) == getattr(by_done, a)
+        assert by_span.args == {"step": 3, "rounds": 2}
+        assert t_in <= by_span.ts and by_span.ts + by_span.dur <= t_out
+        with pytest.raises(RuntimeError):
+            with tr.span("edge.decode", "edge"):
+                raise RuntimeError("body failed")
+        assert len(tr) == 2
+
+    def test_span_lands_on_profiler_host_plane(self, tmp_path):
+        """Under a profiler trace a live span is a TraceMe on the host
+        plane, on the device trace's clock, its args as event stats and
+        nested as it was opened."""
+        from jax.profiler import ProfileData
+        tr = Tracer()
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        with jax.profiler.trace(str(tmp_path), profiler_options=opts):
+            with tr.span("edge.catchup", "edge", step=7, rounds=3) as sp:
+                with tr.span("catchup.wait", "edge", step=7):
+                    jnp.ones(8).sum().block_until_ready()
+                sp.set(replayed=5)
+        path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                          recursive=True)
+        evs = {}
+        for plane in ProfileData.from_file(path).planes:
+            if plane.name.startswith("/host:CPU"):
+                for line in plane.lines:
+                    for ev in line.events:
+                        if ev.name in ("edge.catchup", "catchup.wait"):
+                            evs[ev.name] = (line.name, ev.start_ns,
+                                            ev.start_ns + ev.duration_ns,
+                                            dict(ev.stats))
+        outer, inner = evs["edge.catchup"], evs["catchup.wait"]
+        assert outer[0] == inner[0], "one host thread"
+        assert outer[1] <= inner[1] < inner[2] <= outer[2]
+        assert outer[3] == {"step": 7, "rounds": 3, "replayed": 5}
+        assert inner[3] == {"step": 7}
+        assert [s.name for s in tr.spans()] == ["catchup.wait",
+                                                "edge.catchup"]
 
     def test_ring_bound_and_dropped(self):
         tr = Tracer(capacity=4)
@@ -266,6 +324,57 @@ class TestTracedIdentity:
         assert sess.export_trace(path) == len(spans)
         load_trace(path)
 
+    def test_sync_bitwise_with_step_spans(self, proto):
+        """With a policy, the sync step's spans are all there —
+        session.policy around the policy's two calls, catchup.dispatch
+        and catchup.wait inside edge.catchup — and the protocol is
+        bitwise that of the untraced session."""
+        cfg, params, stream = proto
+        r0, _ = _run(cfg, params, stream, SessionConfig(policy=FixedPolicy()))
+        r1, sess = _run(cfg, params, stream,
+                        SessionConfig(policy=FixedPolicy(), trace=True))
+        _assert_bitwise(r0, r1)
+        spans = sess.tracer.spans()
+        S = stream.shape[1]
+        n_catchup = int(r1["triggered"].any(axis=0).sum())
+        count = lambda n: sum(s.name == n for s in spans)
+        assert count("session.policy") == 2 * S
+        assert count("edge.decode") == count("edge.trigger") == S
+        assert (count("edge.catchup") == count("catchup.dispatch")
+                == count("catchup.wait") == n_catchup)
+        outer = [s for s in spans if s.name == "edge.catchup"]
+        for name in ("catchup.dispatch", "catchup.wait"):
+            kids = [s for s in spans if s.name == name]
+            for o, k in zip(outer, kids):
+                assert k.args["step"] == o.args["step"]
+                assert o.ts <= k.ts and k.ts + k.dur <= o.ts + o.dur
+
+    def test_sync_catchup_counts(self, proto):
+        """Each edge.catchup span carries the call's rounds (the longest
+        triggered backlog), rows (B) and replayed tokens (what comms
+        charged), and the engine's counters sum them."""
+        cfg, params, stream = proto
+        r, sess = _run(cfg, params, stream, SessionConfig(trace=True))
+        trig = r["triggered"]                       # (B, S)
+        B, S = trig.shape
+        want, server_pos = [], np.zeros(B, np.int64)
+        for t in range(S):
+            if trig[:, t].any():
+                backlog = np.where(trig[:, t], t + 1 - server_pos, 0)
+                want.append({"step": t, "rounds": int(backlog.max()),
+                             "rows": B, "replayed": int(backlog.sum())})
+                server_pos = np.where(trig[:, t], t + 1, server_pos)
+        got = [{k: s.args[k] for k in ("step", "rounds", "rows", "replayed")}
+               for s in sess.tracer.spans() if s.name == "edge.catchup"]
+        assert got == want and any(w["rounds"] > 1 for w in want)
+        snap = sess.metrics()
+        assert snap["catchup.calls"] == len(want)
+        assert snap["catchup.rounds"] == sum(w["rounds"] for w in want)
+        assert "catchup.row_rounds" not in snap
+        assert snap["catchup.replayed"] == sum(w["replayed"] for w in want)
+        assert (snap["catchup.replayed"]
+                == sess.report()["bytes_sent"] // TOKEN_BYTES)
+
     def test_scan_bitwise(self, proto):
         cfg, params, stream = proto
         r0, _ = _run(cfg, params, stream, SessionConfig(mode="scan"))
@@ -381,10 +490,18 @@ class TestDisabledPath:
         monkeypatch.setattr(Tracer, "__init__", boom)
         monkeypatch.setattr(Tracer, "done", boom)
         monkeypatch.setattr(Tracer, "add", boom)
+        monkeypatch.setattr(Tracer, "span", boom)
+        # nor is a profiler annotation constructed
+        monkeypatch.setattr(trace_mod, "TraceAnnotation", boom)
         cfg, params, stream = proto
         r, sess = _run(cfg, params, stream, SessionConfig())
         assert sess.tracer is None
         assert r["triggered"].any()
+        # the policy's calls and the catch-up's children are span sites too
+        r, sess = _run(cfg, params, stream,
+                       SessionConfig(policy=FixedPolicy()))
+        assert sess.tracer is None and r["triggered"].any()
+        assert sess.metrics()["catchup.calls"] > 0
 
     def test_export_trace_refuses_when_off(self, proto):
         cfg, params, stream = proto
@@ -403,3 +520,70 @@ class TestDisabledPath:
         s2 = eng.session(SessionConfig())
         s2._ensure_open()
         assert eng._tracer is None
+
+
+# -- named scopes split the catch-up's compiled program ----------------------
+
+def _computations(hlo: str) -> dict:
+    """Compiled HLO text -> {computation name: its instruction lines}."""
+    comps, name = {}, None
+    for line in hlo.splitlines():
+        m = re.match(r"^(?:ENTRY )?%?([\w.\-]+) ", line)
+        if m and line.rstrip().endswith("{"):
+            name = m.group(1)
+            comps[name] = []
+        elif name is not None and line.startswith("  "):
+            comps[name].append(line)
+    return comps
+
+
+def _called(comps: dict, root: str) -> set:
+    """``root`` and every computation it runs, transitively: fusions,
+    loops and branches (a reduction's scalar combiner, ``to_apply``,
+    carries the bare op name and is left out)."""
+    seen, todo = set(), [root]
+    while todo:
+        c = todo.pop()
+        if c in seen or c not in comps:
+            continue
+        seen.add(c)
+        for line in comps[c]:
+            todo += re.findall(r"(?:calls|body|condition)=%([\w.\-]+)", line)
+            for group in re.findall(r"(?:calls|branch_computations)="
+                                    r"\{([^}]*)\}", line):
+                todo += re.findall(r"%([\w.\-]+)", group)
+    return seen
+
+
+def test_catchup_named_scopes_split_the_replay_loop(proto):
+    """The contract the replay-loop reader of the device trace relies on:
+    in ``_catchup_impl`` compiled, every operation in the body of the
+    top-level while carries ``catchup.round`` (but the loop counter
+    ``fori_loop`` adds), the masked cache write-back inside it
+    ``decode.merge``, and the v head and the combine sit outside it."""
+    cfg, params, _ = proto
+    B = 3
+    eng = CollaborativeEngine(params, cfg, batch=B, max_len=16)
+    hlo = jax.jit(eng._catchup_impl).lower(
+        params, eng.server.cache, eng._history, jnp.zeros(B, jnp.int32),
+        jnp.asarray(4, jnp.int32), jnp.ones(B, bool),
+        jnp.zeros(B, jnp.float32)).compile().as_text()
+    comps = _computations(hlo)
+    entry = re.search(r"^ENTRY %?([\w.\-]+)", hlo, re.M).group(1)
+    loops = [line for line in comps[entry] if " while(" in line]
+    assert len(loops) == 1, "one top-level loop: the replay rounds"
+    body = re.search(r"body=%([\w.\-]+)", loops[0]).group(1)
+    inside = _called(comps, body)
+    names = lambda cs: [n for c in cs for line in comps[c]
+                        for n in re.findall(r'op_name="([^"]*)"', line)]
+    in_loop = names(inside)
+    assert in_loop
+    prefix = "jit(_catchup_impl)/while/body/"
+    for n in in_loop:
+        assert (n.startswith(prefix + "catchup.round/")
+                or n == prefix + "add"), n
+    assert any("/decode.merge/" in n for n in in_loop)
+    outside = names(set(comps) - inside)
+    for scope in ("catchup.v_head", "catchup.combine"):
+        assert any(f"/{scope}/" in n for n in outside), scope
+        assert not any(scope in n for n in in_loop), scope
