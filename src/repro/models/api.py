@@ -41,12 +41,18 @@ def init_cache(cfg: ArchConfig, batch: int, seq_len: int):
 
 
 def decode_step(params, cfg: ArchConfig, cache, tokens_t, pos, *,
-                with_logits: bool = True):
+                active=None, with_logits: bool = True):
     """with_logits=False skips the unembed projection (monitoring-only
     decode: the collaborative protocol consumes hidden scores, not
-    next-token logits — the tokens come from the monitored stream)."""
+    next-token logits — the tokens come from the monitored stream).
+
+    ``active`` ((B,) bool, or None for every row): the row mask of the
+    cache writes.  A row where it is False gets every cache leaf back
+    bit-unchanged — its K/V slot rewritten with the slot's own value, its
+    recurrent state with the state it read — while its hidden state and
+    logits are garbage (decoded densely, discarded by the caller)."""
     return _impl(cfg).decode_step(params, cfg, cache, tokens_t, pos,
-                                  with_logits=with_logits)
+                                  active=active, with_logits=with_logits)
 
 
 # ---------------------------------------------------------------------------

@@ -14,11 +14,11 @@ import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
 from repro.nn.attention import (KVCache, MLACache, cross_attn, gqa_decode,
-                                gqa_prefill, init_gqa, init_mla, mla_decode,
-                                mla_prefill)
+                                gqa_prefill, init_gqa, init_mla, layer_of,
+                                mla_decode, mla_prefill)
 from repro.nn.mlp import init_swiglu, swiglu
 from repro.nn.moe import init_moe, moe_dispatch
-from repro.nn.module import Params
+from repro.nn.module import Params, rowwise
 from repro.nn.norms import init_rmsnorm, rmsnorm
 
 
@@ -50,16 +50,50 @@ def scan_layers(body: Callable, h, stacked: Params, *, remat: bool = True,
     return h, aux
 
 
-def scan_layers_decode(body: Callable, h_t, stacked: Params, caches, pos,
-                       unroll: bool = False):
-    """body(layer_params, h_t, cache, pos) -> (h_t, new_cache)."""
+def scan_layers_decode(body: Callable, h_t, stacked: Params, caches, pos, *,
+                       at: tuple = (), unroll: bool = False):
+    """body(layer_params, h_t, caches, layer, pos) -> (h_t, caches), where
+    ``layer = at + (i,)`` indexes the leading axes of every leaf of the
+    stacked ``caches`` (``at``: the indices of an enclosing stack).
 
-    def step(h, xs):
-        lp, cache = xs
-        h, new_cache = body(lp, h, cache, pos)
-        return h, new_cache
+    The caches are carried through the loop, not scanned: as the scan's
+    ``xs``/``ys`` every layer's cache would be copied out of the stack and
+    back in on every step, though a token writes one slot of it.  Carried,
+    a layer writes its slot (``nn.attention.write_slot``) or its recurrent
+    state (``recurrent_step``) into the stack in place, and reads its layer
+    where attention consumes it."""
+    n = jax.tree.leaves(stacked)[0].shape[0]
 
-    return jax.lax.scan(step, h_t, (stacked, caches), unroll=unroll)
+    def step(carry, xs):
+        h, caches = carry
+        lp, i = xs
+        h, caches = body(lp, h, caches, at + (i,), pos)
+        return (h, caches), None
+
+    (h_t, caches), _ = jax.lax.scan(step, (h_t, caches),
+                                    (stacked, jnp.arange(n)), unroll=unroll)
+    return h_t, caches
+
+
+def recurrent_step(step: Callable, h_t, caches, at: tuple, pos, active=None):
+    """One recurrent layer over its stacked state: read layer ``at`` of
+    ``caches``, run ``step(pos, h_t, state) -> (h_t, new_state)`` per row
+    (``rowwise``), and write the new state back into the stack in place.
+    A recurrent state is replaced whole every step, so with ``active``
+    ((B,) bool) the rows where it is False take the state they read back,
+    in the same pass that writes the layer."""
+    old = jax.tree.map(lambda leaf: layer_of(leaf, at), caches)
+    h_t, new = rowwise(step, pos)(h_t, old)
+
+    def put(leaf, n, o):
+        if active is not None:
+            n = jnp.where(jnp.reshape(active, (-1,) + (1,) * (n.ndim - 1)), n, o)
+        return jax.lax.dynamic_update_slice(
+            leaf, n.astype(leaf.dtype).reshape((1,) * len(at) + n.shape),
+            tuple(at) + (0,) * n.ndim)
+
+    with jax.named_scope("decode.merge"):
+        return h_t, jax.tree.map(put, caches, new, old)
 
 
 # ---------------------------------------------------------------------------
@@ -194,28 +228,37 @@ def block_prefill(p: Params, h: jnp.ndarray, cfg: ArchConfig, *,
 
 
 def block_decode(p: Params, h: jnp.ndarray, cache, pos, cfg: ArchConfig, *,
-                 moe: bool = False, window: int = 0):
-    hn = rmsnorm(p["ln_attn"], h, cfg.norm_eps)
+                 moe: bool = False, window: int = 0, at: tuple = (),
+                 active=None):
+    """One token through one block at ``pos`` (scalar or one per row, see
+    ``rowwise``); ``cache`` is the stacked KV cache and ``at`` this block's
+    layer in it (see ``gqa_decode``)."""
+    hn = rowwise(lambda _, h: rmsnorm(p["ln_attn"], h, cfg.norm_eps), pos)(h)
     if cfg.use_mla:
         a, new_cache = mla_decode(p["attn"], hn, cache, pos, n_heads=cfg.n_heads,
                                   qk_nope=cfg.qk_nope_dim, qk_rope=cfg.qk_rope_dim,
                                   v_dim=cfg.v_head_dim, kv_lora=cfg.kv_lora_rank,
-                                  rope_theta=cfg.rope_theta, compute_dtype=cdt(cfg))
+                                  rope_theta=cfg.rope_theta, compute_dtype=cdt(cfg),
+                                  at=at, active=active)
     else:
         a, new_cache = gqa_decode(p["attn"], hn, cache, pos, n_heads=cfg.n_heads,
                                   n_kv=cfg.n_kv_heads, head_dim=cfg.resolved_head_dim,
                                   rope_theta=cfg.rope_theta, window=window,
-                                  compute_dtype=cdt(cfg))
-    h = h + a
-    hn = rmsnorm(p["ln_mlp"], h, cfg.norm_eps)
-    if moe:
-        m, _ = moe_dispatch(p["moe"], hn[:, None, :], n_experts=cfg.n_experts,
-                            top_k=cfg.top_k, capacity_factor=cfg.capacity_factor,
-                            compute_dtype=cdt(cfg), impl=cfg.moe_impl)
-        m = m[:, 0]
-    else:
-        m = swiglu(p["mlp"], hn, compute_dtype=cdt(cfg))
-    return h + m, new_cache
+                                  compute_dtype=cdt(cfg), at=at, active=active)
+
+    def ffn(_, h, a):
+        h = h + a
+        hn = rmsnorm(p["ln_mlp"], h, cfg.norm_eps)
+        if moe:
+            m, _ = moe_dispatch(p["moe"], hn[:, None, :], n_experts=cfg.n_experts,
+                                top_k=cfg.top_k, capacity_factor=cfg.capacity_factor,
+                                compute_dtype=cdt(cfg), impl=cfg.moe_impl)
+            m = m[:, 0]
+        else:
+            m = swiglu(p["mlp"], hn, compute_dtype=cdt(cfg))
+        return h + m
+
+    return rowwise(ffn, pos)(h, a), new_cache
 
 
 def init_kv_cache(cfg: ArchConfig, batch: int, capacity: int):

@@ -17,10 +17,10 @@ import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
 from repro.models.base import (block_decode, block_prefill, cdt, decode_capacity,
-                               init_block, init_kv_cache, pdt, scan_layers,
-                               scan_layers_decode, stack_init)
+                               init_block, init_kv_cache, pdt, recurrent_step,
+                               scan_layers, scan_layers_decode, stack_init)
 from repro.nn.embedding import embed, init_embedding, unembed
-from repro.nn.module import Params
+from repro.nn.module import Params, rowwise
 from repro.nn.norms import init_rmsnorm, rmsnorm
 from repro.nn.ssm import (init_mamba2, init_ssm_cache, mamba2_decode,
                           mamba2_prefill)
@@ -130,35 +130,45 @@ def init_cache(cfg: ArchConfig, batch: int, seq_len: int, image_tokens: int = 0)
 
 
 def decode_step(params: Params, cfg: ArchConfig, cache, tokens_t, pos, *,
-                with_logits: bool = True):
+                active=None, with_logits: bool = True):
+    """One decode step; ``pos`` and ``active`` as in
+    ``transformer.decode_step``."""
     n_super, k, tail = _layout(cfg)
-    h = embed(params["embed"], tokens_t, cdt(cfg))
+    h = rowwise(lambda _, t: embed(params["embed"], t, cdt(cfg)), pos)(tokens_t)
     shared = params["shared"]
     cap = cache["attn"].k.shape[2]
     win = cap if cfg.long_context_window else 0
 
-    def mamba_body(lp, h, c, _pos):
-        y, nc = mamba2_decode(lp["mamba"], rmsnorm(lp["ln"], h, cfg.norm_eps), c,
-                              expand=cfg.ssm_expand, state=cfg.ssm_state,
-                              conv_k=cfg.ssm_conv, compute_dtype=cdt(cfg))
-        return h + y, nc
+    def mamba_body(lp, h, caches, at, pos):
+        def step(_, h, c):
+            y, nc = mamba2_decode(lp["mamba"], rmsnorm(lp["ln"], h, cfg.norm_eps),
+                                  c, expand=cfg.ssm_expand, state=cfg.ssm_state,
+                                  conv_k=cfg.ssm_conv, compute_dtype=cdt(cfg))
+            return h + y, nc
 
-    def super_body(h, xs):
-        lp, sc, ac = xs
-        h, new_sc = scan_layers_decode(mamba_body, h, lp, sc, pos,
-                                       unroll=cfg.scan_unroll)
-        h, new_ac = block_decode(shared, h, ac, pos, cfg, window=win)
-        return h, (new_sc, new_ac)
+        return recurrent_step(step, h, caches, at, pos, active)
 
-    h, (new_ssm, new_attn) = jax.lax.scan(
-        super_body, h, (params["mamba_blocks"], cache["ssm"], cache["attn"]),
-        unroll=cfg.scan_unroll)
+    def super_body(carry, xs):
+        h, ssm, attn = carry
+        lp, s = xs
+        h, ssm = scan_layers_decode(mamba_body, h, lp, ssm, pos, at=(s,),
+                                    unroll=cfg.scan_unroll)
+        h, attn = block_decode(shared, h, attn, pos, cfg, window=win, at=(s,),
+                               active=active)
+        return (h, ssm, attn), None
+
+    (h, new_ssm, new_attn), _ = jax.lax.scan(
+        super_body, (h, cache["ssm"], cache["attn"]),
+        (params["mamba_blocks"], jnp.arange(n_super)), unroll=cfg.scan_unroll)
     new_tail = None
     if tail:
         h, new_tail = scan_layers_decode(mamba_body, h, params["tail"],
                                          cache["ssm_tail"], pos,
                                          unroll=cfg.scan_unroll)
-    h = rmsnorm(params["ln_f"], h[:, None], cfg.norm_eps)[:, 0]
-    tab = params["embed"] if cfg.tie_embeddings else params["unembed"]
-    logits = unembed(tab, h, cdt(cfg)) if with_logits else None
+    def head(_, h):
+        h = rmsnorm(params["ln_f"], h[:, None], cfg.norm_eps)[:, 0]
+        tab = params["embed"] if cfg.tie_embeddings else params["unembed"]
+        return (unembed(tab, h, cdt(cfg)) if with_logits else None), h
+
+    logits, h = rowwise(head, pos)(h)
     return logits, h, {"ssm": new_ssm, "ssm_tail": new_tail, "attn": new_attn}

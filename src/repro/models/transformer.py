@@ -24,7 +24,7 @@ from repro.nn.attention import cross_attn, init_gqa
 from repro.nn.embedding import (codebook_embed, codebook_unembed, embed,
                                 init_codebook_embedding, init_embedding,
                                 unembed)
-from repro.nn.module import Params, init_linear, linear
+from repro.nn.module import Params, init_linear, linear, rowwise
 from repro.nn.norms import init_rmsnorm, rmsnorm
 
 
@@ -203,72 +203,75 @@ def init_cache(cfg: ArchConfig, batch: int, seq_len: int,
 
 
 def decode_step(params: Params, cfg: ArchConfig, cache, tokens_t: jnp.ndarray,
-                pos, *, with_logits: bool = True
+                pos, *, active=None, with_logits: bool = True
                 ) -> Tuple[jnp.ndarray, jnp.ndarray, Dict]:
     """One decode step. tokens_t: (B,[K]) -> (logits, hidden_t, new_cache).
-    with_logits=False returns logits=None (monitoring-only decode)."""
+    with_logits=False returns logits=None (monitoring-only decode).
+    ``pos``: a scalar, or (B,) for a position per row (each row decoded
+    alone, ``rowwise``).  ``active`` ((B,) bool or None): rows where it is
+    False get their cache back bit-unchanged (``nn.attention.write_slot``);
+    their hidden state and logits are garbage."""
     lay = _layer_layout(cfg)
     # Window handling: the cache was sized by decode_capacity; if it is
     # smaller than the logical context we run it as a ring buffer (SWA).
-    if cfg.family == "audio":
-        h = codebook_embed(params["embed"], tokens_t[:, None], cdt(cfg))[:, 0]
-    else:
-        h = embed(params["embed"], tokens_t, cdt(cfg))
+    def embed_in(_, t):
+        if cfg.family == "audio":
+            return codebook_embed(params["embed"], t[:, None], cdt(cfg))[:, 0]
+        return embed(params["embed"], t, cdt(cfg))
 
-    new_cache = {}
+    h = rowwise(embed_in, pos)(tokens_t)
+
     if lay["kind"] == "vlm":
         n_sup, per = lay["n_super"], lay["per_super"]
         cap = cache["self"].k.shape[2]
         win = cap if cfg.sliding_window or cfg.long_context_window else 0
 
-        def self_body(lp, h, c, pos):
-            return block_decode(lp, h, c, pos, cfg, window=win)
+        def self_body(first):
+            # the self caches stack every layer flat: (n_super*per + tail, ...)
+            def body(lp, h, c, at, pos):
+                return block_decode(lp, h, c, pos, cfg, window=win,
+                                    at=(first + at[0],), active=active)
+            return body
 
-        # scan over super-blocks: reshape self caches to (n_super, per, ...)
-        selfc = jax.tree.map(
-            lambda l: l[: n_sup * per].reshape((n_sup, per) + l.shape[1:]),
-            cache["self"])
-
-        def super_body(h, xs):
-            lp, c, ck, cv = xs
-            h, nc = scan_layers_decode(self_body, h, lp["blocks"], c, pos,
-                                       unroll=cfg.scan_unroll)
+        def super_body(carry, xs):
+            h, selfc = carry
+            lp, ck, cv, s = xs
+            h, selfc = scan_layers_decode(self_body(s * per), h, lp["blocks"],
+                                          selfc, pos, unroll=cfg.scan_unroll)
             cp = lp["cross"]
-            hn = rmsnorm(cp["ln"], h[:, None], cfg.norm_eps)
-            catt = cross_attn_decode(cp["attn"], hn[:, 0], ck, cv, cfg)
-            h = (h.astype(jnp.float32)
-                 + jnp.tanh(cp["gate"].astype(jnp.float32)) * catt.astype(jnp.float32)
-                 ).astype(cdt(cfg))
-            return h, nc
+
+            def cross(_, h, ck, cv):
+                hn = rmsnorm(cp["ln"], h[:, None], cfg.norm_eps)
+                catt = cross_attn_decode(cp["attn"], hn[:, 0], ck, cv, cfg)
+                return (h.astype(jnp.float32) + jnp.tanh(cp["gate"].astype(jnp.float32))
+                        * catt.astype(jnp.float32)).astype(cdt(cfg))
+
+            return (rowwise(cross, pos)(h, ck, cv), selfc), None
 
         stacked = {"blocks": params["blocks"], "cross": params["cross"]}
-        h, new_self = jax.lax.scan(
-            super_body, h, (stacked, selfc, cache["cross_k"], cache["cross_v"]),
+        (h, selfc), _ = jax.lax.scan(
+            super_body, (h, cache["self"]),
+            (stacked, cache["cross_k"], cache["cross_v"], jnp.arange(n_sup)),
             unroll=cfg.scan_unroll)
-        new_self = jax.tree.map(
-            lambda l: l.reshape((n_sup * per,) + l.shape[2:]), new_self)
         if lay.get("tail"):
-            tailc = jax.tree.map(lambda l: l[n_sup * per:], cache["self"])
-            def body(lp, h, c, pos):
-                return block_decode(lp, h, c, pos, cfg, window=win)
-            h, new_tail = scan_layers_decode(body, h, params["tail"], tailc, pos,
-                                             unroll=cfg.scan_unroll)
-            new_self = jax.tree.map(lambda a, b: jnp.concatenate([a, b], 0),
-                                    new_self, new_tail)
-        new_cache = {"self": new_self, "cross_k": cache["cross_k"],
+            h, selfc = scan_layers_decode(self_body(n_sup * per), h,
+                                          params["tail"], selfc, pos,
+                                          unroll=cfg.scan_unroll)
+        new_cache = {"self": selfc, "cross_k": cache["cross_k"],
                      "cross_v": cache["cross_v"]}
     elif lay["kind"] == "moe":
         capc = cache["moe"].ckv if cfg.use_mla else cache["moe"].k
         cap = capc.shape[2]
         win = cap if cfg.long_context_window else 0
 
-        def dense_body(lp, h, c, pos):
+        def dense_body(lp, h, c, at, pos):
             return block_decode(lp, h, c, pos,
                                 cfg.replace(d_ff=cfg.d_ff or cfg.moe_d_ff),
-                                window=win)
+                                window=win, at=at, active=active)
 
-        def moe_body(lp, h, c, pos):
-            return block_decode(lp, h, c, pos, cfg, moe=True, window=win)
+        def moe_body(lp, h, c, at, pos):
+            return block_decode(lp, h, c, pos, cfg, moe=True, window=win,
+                                at=at, active=active)
 
         new_dense = None
         if lay["dense"]:
@@ -283,16 +286,21 @@ def decode_step(params: Params, cfg: ArchConfig, cache, tokens_t: jnp.ndarray,
         cap = cache["blocks"].k.shape[2]
         win = cap if (cfg.sliding_window or cfg.long_context_window) else 0
 
-        def body(lp, h, c, pos):
-            return block_decode(lp, h, c, pos, cfg, window=win)
+        def body(lp, h, c, at, pos):
+            return block_decode(lp, h, c, pos, cfg, window=win, at=at,
+                                active=active)
 
         h, new_blocks = scan_layers_decode(body, h, params["blocks"],
                                            cache["blocks"], pos,
                                            unroll=cfg.scan_unroll)
         new_cache = {"blocks": new_blocks}
 
-    h = rmsnorm(params["ln_f"], h[:, None], cfg.norm_eps)[:, 0]
-    return (_logits(params, cfg, h) if with_logits else None), h, new_cache
+    def head(_, h):
+        h = rmsnorm(params["ln_f"], h[:, None], cfg.norm_eps)[:, 0]
+        return (_logits(params, cfg, h) if with_logits else None), h
+
+    logits, h = rowwise(head, pos)(h)
+    return logits, h, new_cache
 
 
 def cross_attn_decode(p: Params, x: jnp.ndarray, k_img: jnp.ndarray,

@@ -10,9 +10,10 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
-from repro.models.base import cdt, pdt, scan_layers, scan_layers_decode, stack_init
+from repro.models.base import (cdt, pdt, recurrent_step, scan_layers,
+                               scan_layers_decode, stack_init)
 from repro.nn.embedding import embed, init_embedding, unembed
-from repro.nn.module import Params
+from repro.nn.module import Params, rowwise
 from repro.nn.norms import init_rmsnorm, rmsnorm
 from repro.nn.xlstm import (MLSTMState, SLSTMState, init_mlstm, init_mlstm_state,
                             init_slstm, init_slstm_state, mlstm_decode,
@@ -105,35 +106,51 @@ def init_cache(cfg: ArchConfig, batch: int, seq_len: int, image_tokens: int = 0)
 
 
 def decode_step(params: Params, cfg: ArchConfig, cache, tokens_t, pos, *,
-                with_logits: bool = True):
+                active=None, with_logits: bool = True):
+    """One decode step; ``pos`` and ``active`` as in
+    ``transformer.decode_step``."""
     n_super, per, tail = _layout(cfg)
-    h = embed(params["embed"], tokens_t, cdt(cfg))
+    h = rowwise(lambda _, t: embed(params["embed"], t, cdt(cfg)), pos)(tokens_t)
 
-    def m_body(lp, h, c, _pos):
-        y, nc = mlstm_decode(lp["cell"], rmsnorm(lp["ln"], h[:, None], cfg.norm_eps)[:, 0],
-                             c, cfg.n_heads, compute_dtype=cdt(cfg))
-        return h + y, nc
+    def m_body(lp, h, caches, at, pos):
+        def step(_, h, c):
+            y, nc = mlstm_decode(lp["cell"],
+                                 rmsnorm(lp["ln"], h[:, None], cfg.norm_eps)[:, 0],
+                                 c, cfg.n_heads, compute_dtype=cdt(cfg))
+            return h + y, nc
+
+        return recurrent_step(step, h, caches, at, pos, active)
 
     new_cache = {"mlstm": None, "slstm": None, "tail": None}
     if n_super:
-        def super_body(h, xs):
-            lp, mc, sc = xs
-            h, new_mc = scan_layers_decode(m_body, h, lp["mlstm"], mc, pos,
-                                           unroll=cfg.scan_unroll)
-            hn = rmsnorm(lp["slstm"]["ln"], h[:, None], cfg.norm_eps)[:, 0]
-            y, new_sc = slstm_step(lp["slstm"]["cell"], hn, sc, cfg.n_heads)
-            y = y * lp["slstm"]["cell"]["norm_scale"].astype(jnp.float32)[None, :]
-            return (h.astype(jnp.float32) + y).astype(cdt(cfg)), (new_mc, new_sc)
+        def super_body(carry, xs):
+            h, mc, sc = carry
+            lp, s = xs
+            h, mc = scan_layers_decode(m_body, h, lp["mlstm"], mc, pos, at=(s,),
+                                       unroll=cfg.scan_unroll)
 
-        h, (new_m, new_s) = jax.lax.scan(
-            super_body, h, (params["super"], cache["mlstm"], cache["slstm"]),
-            unroll=cfg.scan_unroll)
+            def s_step(_, h, c):
+                hn = rmsnorm(lp["slstm"]["ln"], h[:, None], cfg.norm_eps)[:, 0]
+                y, nc = slstm_step(lp["slstm"]["cell"], hn, c, cfg.n_heads)
+                y = y * lp["slstm"]["cell"]["norm_scale"].astype(jnp.float32)[None, :]
+                return (h.astype(jnp.float32) + y).astype(cdt(cfg)), nc
+
+            h, sc = recurrent_step(s_step, h, sc, (s,), pos, active)
+            return (h, mc, sc), None
+
+        (h, new_m, new_s), _ = jax.lax.scan(
+            super_body, (h, cache["mlstm"], cache["slstm"]),
+            (params["super"], jnp.arange(n_super)), unroll=cfg.scan_unroll)
         new_cache["mlstm"], new_cache["slstm"] = new_m, new_s
     if tail:
         h, new_t = scan_layers_decode(m_body, h, params["tail"], cache["tail"], pos,
                                       unroll=cfg.scan_unroll)
         new_cache["tail"] = new_t
 
-    h = rmsnorm(params["ln_f"], h[:, None], cfg.norm_eps)[:, 0]
-    tab = params["embed"] if cfg.tie_embeddings else params["unembed"]
-    return (unembed(tab, h, cdt(cfg)) if with_logits else None), h, new_cache
+    def head(_, h):
+        h = rmsnorm(params["ln_f"], h[:, None], cfg.norm_eps)[:, 0]
+        tab = params["embed"] if cfg.tie_embeddings else params["unembed"]
+        return (unembed(tab, h, cdt(cfg)) if with_logits else None), h
+
+    logits, h = rowwise(head, pos)(h)
+    return logits, h, new_cache

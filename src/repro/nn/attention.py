@@ -9,6 +9,8 @@ XLA fallback matching the Pallas flash kernel in kernels/flash_attention.py
 KV caches are fixed-capacity ring-free buffers: (B, S_max, n_kv, hd) with a
 scalar fill pointer; decode writes at ``pos`` and masks entries >= pos+1.
 Sliding-window decode uses a modular ring buffer of capacity ``window``.
+A model stacks its layers' caches on leading axes; the decode paths take the
+whole stack and the layer's index ``at`` and write the one slot in place.
 """
 from __future__ import annotations
 
@@ -18,7 +20,7 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
-from repro.nn.module import Params, init_linear, linear
+from repro.nn.module import Params, init_linear, linear, rowwise
 from repro.nn.rotary import apply_rope
 
 NEG_INF = -1e30
@@ -198,24 +200,97 @@ def gqa_prefill(p: Params, x: jnp.ndarray, *, n_heads: int, n_kv: int,
     return y
 
 
+def layer_of(leaf: jnp.ndarray, at: tuple) -> jnp.ndarray:
+    """Layer ``at`` (indices into the leading axes) of a stacked cache leaf;
+    ``at == ()`` is the leaf itself."""
+    n = len(at)
+    if not n:
+        return leaf
+    start = tuple(at) + (0,) * (leaf.ndim - n)
+    return jax.lax.dynamic_slice(leaf, start, (1,) * n + leaf.shape[n:]
+                                 ).reshape(leaf.shape[n:])
+
+
+def write_slot(leaf: jnp.ndarray, at: tuple, slot, new: jnp.ndarray,
+               active: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+    """Write one token's entry ``new`` (B, ...) into time slot ``slot`` of
+    layer ``at`` of the stacked leaf (*layers, B, C, ...), in place.
+
+    ``slot`` is a scalar (one slot for the batch) or (B,), a slot per row.
+    Unmasked at one slot it is a dynamic_update_slice; otherwise a scatter
+    whose batching dimension is the leaf's batch axis, so each row writes
+    its own slot and a batch-sharded cache writes locally.  Masking
+    contract: with ``active`` ((B,) bool) a row where it is False writes
+    back the slot's own value, so its whole cache comes back bit-unchanged;
+    the select touches one slot a row, never the cache.
+    """
+    n = len(at)
+    new = new.astype(leaf.dtype)
+    with jax.named_scope("decode.merge"):
+        if jnp.ndim(slot) == 0 and active is None:
+            start = tuple(at) + (0, slot) + (0,) * (leaf.ndim - n - 2)
+            upd = new.reshape((1,) * n + (new.shape[0], 1) + new.shape[1:])
+            return jax.lax.dynamic_update_slice(leaf, upd, start)
+        # row b's entry goes to leaf[*at, b, slot[b]]: indices (B, n + 1)
+        slot = jnp.broadcast_to(jnp.asarray(slot, jnp.int32), new.shape[:1])
+        idx = jnp.stack([jnp.broadcast_to(jnp.asarray(i, jnp.int32), slot.shape)
+                         for i in at] + [slot], axis=1)
+        point = tuple(range(n)) + (n + 1,)           # layer axes + time axis
+        window = tuple(range(1, new.ndim))
+        if active is not None:
+            old = jax.lax.gather(
+                leaf, idx, jax.lax.GatherDimensionNumbers(
+                    offset_dims=window, collapsed_slice_dims=point,
+                    start_index_map=point, operand_batching_dims=(n,),
+                    start_indices_batching_dims=(0,)),
+                (1,) * (n + 2) + leaf.shape[n + 2:],
+                mode=jax.lax.GatherScatterMode.CLIP)
+            new = jnp.where(jnp.reshape(active, (-1,) + (1,) * (new.ndim - 1)),
+                            new, old)
+        return jax.lax.scatter(
+            leaf, idx, new, jax.lax.ScatterDimensionNumbers(
+                update_window_dims=window, inserted_window_dims=point,
+                scatter_dims_to_operand_dims=point, operand_batching_dims=(n,),
+                scatter_indices_batching_dims=(0,)),
+            indices_are_sorted=True, unique_indices=True,
+            mode=jax.lax.GatherScatterMode.CLIP)
+
+
 def gqa_decode(p: Params, x: jnp.ndarray, cache: KVCache, pos: jnp.ndarray, *,
                n_heads: int, n_kv: int, head_dim: int, rope_theta: float = 1e4,
-               window: int = 0, compute_dtype=jnp.bfloat16):
-    """x: (B, d_model) one token. Returns (y, new_cache)."""
-    B = x.shape[0]
-    C = cache.k.shape[1]
-    q = linear(p["wq"], x, compute_dtype=compute_dtype).reshape(B, n_heads, head_dim)
-    k = linear(p["wk"], x, compute_dtype=compute_dtype).reshape(B, n_kv, head_dim)
-    v = linear(p["wv"], x, compute_dtype=compute_dtype).reshape(B, n_kv, head_dim)
-    posb = jnp.full((B, 1), pos)
-    q = apply_rope(q[:, None], posb, rope_theta)[:, 0]
-    k = apply_rope(k[:, None], posb, rope_theta)[:, 0]
+               window: int = 0, compute_dtype=jnp.bfloat16, at: tuple = (),
+               active: Optional[jnp.ndarray] = None):
+    """x: (B, d_model) one token at ``pos`` (a scalar, or (B,): a position
+    per row, each row decoded alone; see ``rowwise``). Returns (y, cache).
+
+    ``cache`` leaves are (*layers, B, C, Hkv, D) and ``at`` the layer's
+    index into the leading axes (``()`` for one unstacked layer): the
+    token's K/V go into slot ``pos`` (``pos % C`` with a window) of that
+    layer by ``write_slot``, under its ``active`` row mask, and attention
+    reads the layer after the write."""
+    C = cache.k.shape[len(at) + 1]
+
+    def project(pos, x):
+        B = x.shape[0]
+        q = linear(p["wq"], x, compute_dtype=compute_dtype).reshape(B, n_heads, head_dim)
+        k = linear(p["wk"], x, compute_dtype=compute_dtype).reshape(B, n_kv, head_dim)
+        v = linear(p["wv"], x, compute_dtype=compute_dtype).reshape(B, n_kv, head_dim)
+        posb = jnp.full((B, 1), pos)
+        q = apply_rope(q[:, None], posb, rope_theta)[:, 0]
+        k = apply_rope(k[:, None], posb, rope_theta)[:, 0]
+        return q, k, v
+
+    def attend(pos, q, k_layer, v_layer):
+        o = decode_attention(q, k_layer, v_layer, pos, window=window)
+        return linear(p["wo"], o.reshape(q.shape[0], n_heads * head_dim),
+                      compute_dtype=compute_dtype)
+
+    q, k, v = rowwise(project, pos)(x)
     slot = pos % C if window else pos
-    k_cache = jax.lax.dynamic_update_slice_in_dim(cache.k, k[:, None].astype(cache.k.dtype), slot, axis=1)
-    v_cache = jax.lax.dynamic_update_slice_in_dim(cache.v, v[:, None].astype(cache.v.dtype), slot, axis=1)
-    o = decode_attention(q, k_cache, v_cache, pos, window=window)
-    y = linear(p["wo"], o.reshape(B, n_heads * head_dim), compute_dtype=compute_dtype)
-    return y, KVCache(k_cache, v_cache)
+    cache = KVCache(write_slot(cache.k, at, slot, k, active),
+                    write_slot(cache.v, at, slot, v, active))
+    y = rowwise(attend, pos)(q, layer_of(cache.k, at), layer_of(cache.v, at))
+    return y, cache
 
 
 # ---------------------------------------------------------------------------
@@ -296,44 +371,54 @@ def mla_prefill(p: Params, x: jnp.ndarray, *, n_heads: int, qk_nope: int,
 def mla_decode(p: Params, x: jnp.ndarray, cache: MLACache, pos: jnp.ndarray, *,
                n_heads: int, qk_nope: int, qk_rope: int, v_dim: int,
                kv_lora: int, rope_theta: float = 1e4,
-               compute_dtype=jnp.bfloat16):
+               compute_dtype=jnp.bfloat16, at: tuple = (),
+               active: Optional[jnp.ndarray] = None):
     """Latent-cache decode: attention runs in the compressed space.
 
     Uses the absorbed-matmul trick: q_nope is mapped through W^kv_b's key half
-    so scores are computed directly against the cached latents.
+    so scores are computed directly against the cached latents.  ``pos``,
+    ``at`` and ``active`` as in ``gqa_decode``.
     """
-    B = x.shape[0]
-    C = cache.ckv.shape[1]
-    posb = jnp.full((B, 1), pos)
-    q = linear(p["wq_b"], linear(p["wq_a"], x, compute_dtype=compute_dtype),
-               compute_dtype=compute_dtype).reshape(B, n_heads, qk_nope + qk_rope)
-    q_nope, q_rope = q[..., :qk_nope], q[..., qk_nope:]
-    q_rope = apply_rope(q_rope[:, None], posb, rope_theta)[:, 0]
-    kv_a = linear(p["wkv_a"], x, compute_dtype=compute_dtype)
-    ckv_t, krope_t = kv_a[..., :-qk_rope], kv_a[..., -qk_rope:]
-    krope_t = apply_rope(krope_t[:, None, None], posb, rope_theta)[:, 0, 0]
-    ckv = jax.lax.dynamic_update_slice_in_dim(
-        cache.ckv, ckv_t[:, None].astype(cache.ckv.dtype), pos, axis=1)
-    krope = jax.lax.dynamic_update_slice_in_dim(
-        cache.krope, krope_t[:, None].astype(cache.krope.dtype), pos, axis=1)
-    # Absorb: W^kv_b = [W_k (kv_lora -> H*qk_nope); W_v (kv_lora -> H*v_dim)]
-    wkv = p["wkv_b"]["w"].astype(compute_dtype).reshape(kv_lora, n_heads, qk_nope + v_dim)
-    wk, wv = wkv[..., :qk_nope], wkv[..., qk_nope:]
-    q_lat = jnp.einsum("bhn,rhn->bhr", q_nope.astype(jnp.float32),
-                       wk.astype(jnp.float32))  # (B,H,kv_lora)
-    scale = 1.0 / math.sqrt(qk_nope + qk_rope)
-    # bf16 latent-cache reads + f32 accumulation (§Perf B2): never
-    # materialise an f32 copy of the (B, C, kv_lora) cache.
-    s = (jnp.einsum("bhr,btr->bht", q_lat.astype(ckv.dtype), ckv,
-                    preferred_element_type=jnp.float32)
-         + jnp.einsum("bhr,btr->bht", q_rope.astype(krope.dtype), krope,
-                      preferred_element_type=jnp.float32)) * scale
-    valid = jnp.arange(C) <= pos
-    s = jnp.where(valid[None, None, :], s, NEG_INF)
-    prob = jax.nn.softmax(s, axis=-1)
-    o_lat = jnp.einsum("bht,btr->bhr", prob.astype(ckv.dtype), ckv,
-                       preferred_element_type=jnp.float32)  # (B,H,r)
-    o = jnp.einsum("bhr,rhv->bhv", o_lat, wv.astype(jnp.float32))
-    y = linear(p["wo"], o.reshape(B, n_heads * v_dim).astype(compute_dtype),
-               compute_dtype=compute_dtype)
-    return y, MLACache(ckv, krope)
+    C = cache.ckv.shape[len(at) + 1]
+
+    def project(pos, x):
+        B = x.shape[0]
+        posb = jnp.full((B, 1), pos)
+        q = linear(p["wq_b"], linear(p["wq_a"], x, compute_dtype=compute_dtype),
+                   compute_dtype=compute_dtype).reshape(B, n_heads, qk_nope + qk_rope)
+        q_nope, q_rope = q[..., :qk_nope], q[..., qk_nope:]
+        q_rope = apply_rope(q_rope[:, None], posb, rope_theta)[:, 0]
+        kv_a = linear(p["wkv_a"], x, compute_dtype=compute_dtype)
+        ckv_t, krope_t = kv_a[..., :-qk_rope], kv_a[..., -qk_rope:]
+        krope_t = apply_rope(krope_t[:, None, None], posb, rope_theta)[:, 0, 0]
+        return q_nope, q_rope, ckv_t, krope_t
+
+    def attend(pos, q_nope, q_rope, ckv, krope):
+        B = q_nope.shape[0]
+        # Absorb: W^kv_b = [W_k (kv_lora -> H*qk_nope); W_v (kv_lora -> H*v_dim)]
+        wkv = p["wkv_b"]["w"].astype(compute_dtype).reshape(kv_lora, n_heads, qk_nope + v_dim)
+        wk, wv = wkv[..., :qk_nope], wkv[..., qk_nope:]
+        q_lat = jnp.einsum("bhn,rhn->bhr", q_nope.astype(jnp.float32),
+                           wk.astype(jnp.float32))  # (B,H,kv_lora)
+        scale = 1.0 / math.sqrt(qk_nope + qk_rope)
+        # bf16 latent-cache reads + f32 accumulation (§Perf B2): never
+        # materialise an f32 copy of the (B, C, kv_lora) cache.
+        s = (jnp.einsum("bhr,btr->bht", q_lat.astype(ckv.dtype), ckv,
+                        preferred_element_type=jnp.float32)
+             + jnp.einsum("bhr,btr->bht", q_rope.astype(krope.dtype), krope,
+                          preferred_element_type=jnp.float32)) * scale
+        valid = jnp.arange(C) <= pos
+        s = jnp.where(valid[None, None, :], s, NEG_INF)
+        prob = jax.nn.softmax(s, axis=-1)
+        o_lat = jnp.einsum("bht,btr->bhr", prob.astype(ckv.dtype), ckv,
+                           preferred_element_type=jnp.float32)  # (B,H,r)
+        o = jnp.einsum("bhr,rhv->bhv", o_lat, wv.astype(jnp.float32))
+        return linear(p["wo"], o.reshape(B, n_heads * v_dim).astype(compute_dtype),
+                      compute_dtype=compute_dtype)
+
+    q_nope, q_rope, ckv_t, krope_t = rowwise(project, pos)(x)
+    cache = MLACache(write_slot(cache.ckv, at, pos, ckv_t, active),
+                     write_slot(cache.krope, at, pos, krope_t, active))
+    y = rowwise(attend, pos)(q_nope, q_rope, layer_of(cache.ckv, at),
+                             layer_of(cache.krope, at))
+    return y, cache

@@ -119,3 +119,28 @@ def cast_tree(tree: Params, dtype) -> Params:
     return jax.tree.map(
         lambda l: l.astype(dtype) if jnp.issubdtype(l.dtype, jnp.floating) else l,
         tree)
+
+
+# ---------------------------------------------------------------------------
+# Per-row decode
+# ---------------------------------------------------------------------------
+
+
+def rowwise(fn: Callable, pos) -> Callable:
+    """``fn(pos, *rows)`` over a batch of rows (each arg a tree of arrays
+    with the batch on axis 0), at one position or at a position per row.
+
+    With a scalar ``pos`` it is ``fn`` itself: the rows decode as one
+    batch.  With a (B,) ``pos`` every row runs alone at its own position,
+    as a batch of one (``vmap`` over the rows, each re-batched to size 1),
+    so a row's numerics depend neither on the rows beside it nor on how
+    the batch is sharded.  Callers keep cache reads and writes outside
+    ``fn``: those stay batch-level, in place on the stacked cache."""
+    if jnp.ndim(pos) == 0:
+        return lambda *rows: fn(pos, *rows)
+
+    def one(p, *rows):
+        out = fn(p, *jax.tree.map(lambda a: a[None], rows))
+        return jax.tree.map(lambda a: a[0], out)
+
+    return lambda *rows: jax.vmap(one)(pos, *rows)

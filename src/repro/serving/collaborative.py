@@ -252,8 +252,9 @@ class CollaborativeEngine:
 
         Named scopes (``jax.named_scope``: op metadata only, numerics
         untouched) split this one program in a device trace: the replay
-        loop's body is ``catchup.round`` (its cache write-back
-        ``decode.merge``), then ``catchup.v_head`` and ``catchup.combine``.
+        loop's body is ``catchup.round`` (in it ``decode.merge``, the cache
+        writes under the row mask: K/V slot writes, recurrent-state
+        selects), then ``catchup.v_head`` and ``catchup.combine``.
         """
         B = triggered.shape[0]
         step_at = self.server.get_step_at(with_logits=False)

@@ -36,12 +36,10 @@ def cache_batch_axes(cfg: ArchConfig, batch: int, max_len: int):
     batch size (e.g. batch == n_heads): those axes don't grow.
 
     A leaf with no differing axis (per-layer scalars broadcast over the
-    batch) raises — such a leaf cannot be vmapped per-element and would
-    silently break the masked-decode contract below.
+    batch) raises — such a leaf has no rows to mask or shard.
 
-    The result is consumed as the ``in_axes``/``out_axes`` tree for the
-    per-element vmap in ``make_step_at`` and as the axis map for its
-    masked cache merge.
+    The result is the axis map of the row selects in ``zero_cache_rows``
+    and ``ServeEngine._step_masked_impl``.
     """
     a = jax.eval_shape(lambda: model_api.init_cache(cfg, batch, max_len))
     b = jax.eval_shape(lambda: model_api.init_cache(cfg, batch + 1, max_len))
@@ -86,63 +84,40 @@ def zero_cache_rows(cache, axes, rows: jnp.ndarray, *, shardings=None):
     return out
 
 
-def make_step_at(cfg: ArchConfig, axes, *, with_logits: bool = True):
+def make_step_at(cfg: ArchConfig, *, with_logits: bool = True):
     """Pure per-element decode step with vector positions and active mask.
 
     Returns ``step_at(params, cache, tokens_t, pos, active)`` where
     tokens_t: (B,[K]), pos: (B,) int32 per-element positions, active: (B,)
-    bool; ``axes`` is the ``cache_batch_axes`` tree.  This is the primitive
-    the collaborative protocol builds on: independent streams at
-    heterogeneous cache depths advance in ONE shape-static SPMD call.
-
-    Masking contract (load-bearing — tests assert it bitwise):
+    bool.  This is the primitive the collaborative protocol builds on:
+    independent streams at heterogeneous cache depths advance in ONE
+    shape-static SPMD call.
 
     * every element is DECODED (dense, discarded compute — the standard
       SPMD masked-semantics trick; there is no data-dependent shape, so
       the function is jit/scan/fori_loop-safe and compiles once);
-    * elements with ``active[i] == False`` have their cache rows returned
-      **bit-unchanged** — not recomputed-and-equal but the original values,
-      selected leaf-wise by ``jnp.where`` along each leaf's batch axis.
-      A masked-out stream's attention reductions in later steps are
-      therefore exactly those of a stream that never decoded;
-    * ``hidden[i]`` for inactive elements is garbage (whatever the dense
-      decode produced) — callers must gate on ``active`` before use, as
-      the collaborative catch-up loop does;
-    * ``pos`` is NOT validated here: callers clip to [0, max_len) (inactive
-      lanes may carry clipped dummy positions, see
+    * each element decodes at its own position as a batch of one
+      (``nn.module.rowwise``), so its numerics do not depend on the batch
+      or its sharding, while the cache is read and written in place, one
+      slot a row (``nn.attention.write_slot``);
+    * ``active`` is the row mask of those cache writes
+      (``model_api.decode_step``): an inactive element's cache rows come
+      back bit-unchanged, and ``hidden[i]`` for it is garbage — callers
+      gate on ``active`` before use, as the collaborative catch-up loop
+      does;
+    * ``pos`` is NOT validated here: an active element's position lies in
+      [0, max_len); an inactive lane may carry any dummy position (its
+      slot index is clamped and the slot written back unchanged, see
       ``collaborative.CollaborativeEngine._catchup_impl``).
 
-    Mechanically each element is decoded at singleton batch via ``vmap``
-    over the cache's discovered batch axes: the vmapped body re-inserts a
-    size-1 batch axis so ``model_api.decode_step`` sees its native layout,
-    then squeezes it back out.  ``with_logits=False`` skips the unembed
-    projection (monitoring-only decode — the protocol consumes hidden
-    scores, not next-token logits).
+    ``with_logits=False`` skips the unembed projection (monitoring-only
+    decode — the protocol consumes hidden scores, not next-token logits).
     """
 
     def step_at(params, cache, tokens_t, pos, active):
-        def one(cache_elem, tok, p):
-            # cache_elem: leaves with the batch axis REMOVED (vmap);
-            # reinsert a singleton batch so decode_step sees its layout.
-            cache1 = jax.tree.map(jnp.expand_dims, cache_elem, axes)
-            logits, hidden, ncache = model_api.decode_step(
-                params, cfg, cache1, tok[None], p, with_logits=with_logits)
-            return (logits[0] if with_logits else None), hidden[0], \
-                jax.tree.map(jnp.squeeze, ncache, axes)
-
-        vm = jax.vmap(one, in_axes=(axes, 0, 0), out_axes=(0, 0, axes))
-        logits, hidden, new_cache = vm(cache, tokens_t,
-                                       jnp.asarray(pos, jnp.int32))
-
-        def merge(new, old, ax):
-            B = active.shape[0]
-            shape = [1] * new.ndim
-            shape[ax] = B
-            return jnp.where(jnp.reshape(active, shape), new, old)
-
-        with jax.named_scope("decode.merge"):
-            cache = jax.tree.map(merge, new_cache, cache, axes)
-        return logits, hidden, cache
+        return model_api.decode_step(
+            params, cfg, cache, tokens_t, jnp.asarray(pos, jnp.int32),
+            active=jnp.asarray(active, bool), with_logits=with_logits)
 
     return step_at
 
@@ -192,8 +167,8 @@ class ServeEngine:
         element is decoded (discarded compute), but elements with
         ``mask[i] == False`` get their cache rows back bit-unchanged.
 
-        Unlike ``make_step_at`` (vmapped singleton decode, which rounds
-        differently from the batched matmul), this is the SAME dense
+        Unlike ``make_step_at`` (each row decoded alone as a batch of one,
+        which rounds differently from the batched matmul), this is the SAME dense
         ``decode_step`` subgraph with a leafwise select epilogue — masked
         rows are bitwise identical to the plain batched ``decode``
         (asserted in tests).  It is the cohort primitive the
@@ -270,8 +245,7 @@ class ServeEngine:
         own jitted loops."""
         if with_logits not in self._step_at:
             self._step_at[with_logits] = jax.jit(make_step_at(
-                self.cfg, cache_batch_axes(self.cfg, self.batch, self.max_len),
-                with_logits=with_logits))
+                self.cfg, with_logits=with_logits))
         return self._step_at[with_logits]
 
     @property

@@ -77,7 +77,7 @@ def test_decode_attention_at_preset_shapes(one_chip, cfg):
 def test_preset_decode_layer(one_chip, cfg, per_row):
     """One layer of the preset's decode step at batch 32 x 2048: the batched
     decode (edge/scan paths) and the catch-up's per-row masked decode."""
-    from repro.serving.engine import cache_batch_axes, make_step_at
+    from repro.serving.engine import make_step_at
     c1 = cfg.replace(n_layers=1)
     params = _specs(jax.eval_shape(
         lambda: model_api.init_model(jax.random.PRNGKey(0), c1)), one_chip)
@@ -85,8 +85,7 @@ def test_preset_decode_layer(one_chip, cfg, per_row):
         lambda: model_api.init_cache(c1, B, MAX_LEN)), one_chip)
     toks = _spec((B,), jnp.int32, one_chip)
     if per_row:
-        step = make_step_at(c1, cache_batch_axes(c1, B, MAX_LEN),
-                            with_logits=False)
+        step = make_step_at(c1, with_logits=False)
         args = (params, cache, toks, toks, _spec((B,), jnp.bool_, one_chip))
     else:
         def step(p, c, t, pos):

@@ -2,6 +2,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.configs import registry
@@ -96,6 +97,135 @@ class TestServeEngine:
         _, h0 = fresh.decode(jnp.stack([stream[0, 0], stream[1, 0]]))
         np.testing.assert_allclose(np.asarray(h)[1], np.asarray(h0)[1],
                                    atol=2e-3, rtol=2e-3)
+
+
+def _merged_step_at(cfg, axes):
+    """The per-element step as it was before it wrote in place: every row
+    decoded alone at its scalar position (vmap over the cache's batch axes,
+    each row re-batched to size one, cache whole), then the whole cache
+    merged under the row mask.  The reference for ``make_step_at``."""
+    from repro.models import api as model_api
+
+    def step(params, cache, tok, pos, active):
+        def one(c, t, p):
+            c1 = jax.tree.map(jnp.expand_dims, c, axes)
+            _, h, nc = model_api.decode_step(params, cfg, c1, t[None], p,
+                                             with_logits=False)
+            return h[0], jax.tree.map(jnp.squeeze, nc, axes)
+
+        hidden, new = jax.vmap(one, in_axes=(axes, 0, 0),
+                               out_axes=(0, axes))(cache, tok, pos)
+
+        def merge(n, o, ax):
+            shape = [1] * n.ndim
+            shape[ax] = active.shape[0]
+            return jnp.where(jnp.reshape(active, shape), n, o)
+
+        return hidden, jax.tree.map(merge, new, cache, axes)
+
+    return jax.jit(step)
+
+
+# the cache families the per-element step writes: dense KV, hybrid SSM
+# state + shared-block KV, xLSTM memories, MLA latents (deepseek), a
+# sliding-window ring (mixtral) and layer-flat self caches (vision)
+PER_ROW_ARCHS = ["granite-8b", "zamba2-7b", "xlstm-350m", "deepseek-v3-671b",
+                 "mixtral-8x22b", "llama-3.2-vision-11b"]
+
+
+class TestPerRowDecode:
+    B, L = 4, 16
+
+    def _setup(self, arch):
+        from repro.models import api as model_api
+        from repro.serving.engine import cache_batch_axes, make_step_at
+        cfg = registry.get_smoke(arch)
+        params = model_api.init_model(KEY, cfg)
+        toks = jnp.asarray(next(tok.lm_batches(0, cfg, self.B, self.L))["tokens"])
+        new = jax.jit(make_step_at(cfg, with_logits=False))
+        old = _merged_step_at(cfg, cache_batch_axes(cfg, self.B, self.L))
+        return (params, toks, new, old, cache_batch_axes(cfg, self.B, self.L),
+                model_api.init_cache(cfg, self.B, self.L))
+
+    @pytest.mark.parametrize("arch", PER_ROW_ARCHS)
+    def test_step_at_writes_in_place_under_the_row_mask(self, arch):
+        """Rows at heterogeneous positions, inactive rows at clipped or
+        out-of-range dummy positions: every leaf of an inactive row comes
+        back bit-unchanged (K/V slots, recurrent state), and the cache and
+        the active rows' hidden states are bitwise those of the per-row
+        decode with a whole-cache merge."""
+        params, toks, new, old, axes, cache = self._setup(arch)
+        cold = cache
+        base = np.array([0, 3, 6, 9])
+        masks = [[1, 1, 1, 1], [1, 0, 1, 1], [0, 1, 1, 0], [1, 1, 0, 1],
+                 [0, 0, 1, 1], [1, 0, 0, 0]]
+        dummy = np.array([self.L - 1, self.L + 3, self.L - 1, 2 * self.L])
+        for r, m in enumerate(masks):
+            act = np.asarray(m, bool)
+            pos = np.where(act, base + r, dummy).astype(np.int32)
+            t = toks[np.arange(self.B), np.minimum(pos, self.L - 1)]
+            _, h1, c1 = new(params, cache, t, jnp.asarray(pos), jnp.asarray(act))
+            h0, c0 = old(params, cache, t, jnp.asarray(pos), jnp.asarray(act))
+            for a, b, ax in zip(jax.tree.leaves(cache), jax.tree.leaves(c1),
+                                jax.tree.leaves(axes)):
+                np.testing.assert_array_equal(np.compress(~act, b, axis=ax),
+                                              np.compress(~act, a, axis=ax))
+            for a, b in zip(jax.tree.leaves(c0), jax.tree.leaves(c1)):
+                np.testing.assert_array_equal(b, a)
+            np.testing.assert_array_equal(np.asarray(h1)[act],
+                                          np.asarray(h0)[act])
+            cache = c1
+        # the active rows did write: the replay is not a no-op
+        assert any(not np.array_equal(a, b) for a, b in zip(
+            jax.tree.leaves(cold), jax.tree.leaves(cache)))
+
+    @pytest.mark.parametrize("arch", ["granite-8b", "zamba2-7b"])
+    def test_step_at_moves_no_whole_cache(self, arch):
+        """Structural guard on the per-element step's jaxpr: no select over
+        a stack of cache layers (the whole-cache merge), no scan that
+        slices a cache stack as ``xs`` or restacks one as ``ys``, and no
+        dynamic slice or update that reads or writes more than one layer
+        of a stack.  What remains are slot writes (scatter), one layer's
+        read for attention and one layer's recurrent state."""
+        params, toks, new, _, axes, cache = self._setup(arch)
+        def shape(x):   # size-1 axes dropped: vmap inserts them
+            return tuple(d for d in getattr(x, "aval", x).shape if d != 1)
+
+        stacks = set()   # shapes of two or more layers of a cache leaf
+        for leaf, ax in zip(jax.tree.leaves(cache), jax.tree.leaves(axes)):
+            stacks.update(shape(jax.ShapeDtypeStruct(leaf.shape[j:], leaf.dtype))
+                          for j in range(ax) if np.prod(leaf.shape[j:ax]) > 1)
+        assert stacks
+        pos = jnp.asarray([0, 3, 6, 9], jnp.int32)
+        jaxpr = jax.make_jaxpr(new)(params, cache, toks[:, 0], pos,
+                                    jnp.ones((self.B,), bool))
+        seen, bad = set(), []
+
+        def walk(j):
+            for e in j.eqns:
+                name = e.primitive.name
+                seen.add(name)
+                if name in ("select_n", "dynamic_slice"):
+                    bad.extend((name, shape(v)) for v in e.outvars
+                               if shape(v) in stacks)
+                if name == "dynamic_update_slice" and shape(e.invars[1]) in stacks:
+                    bad.append((name, shape(e.invars[1])))
+                if name == "scan":
+                    k = e.params["num_consts"] + e.params["num_carry"]
+                    bad.extend(("scan xs", shape(v)) for v in e.invars[k:]
+                               if shape(v) in stacks)
+                    bad.extend(("scan ys", shape(v))
+                               for v in e.outvars[e.params["num_carry"]:]
+                               if shape(v) in stacks)
+                for p in e.params.values():
+                    for sub in (p if isinstance(p, (tuple, list)) else [p]):
+                        inner = getattr(sub, "jaxpr", sub)
+                        if hasattr(inner, "eqns"):
+                            walk(inner)
+
+        walk(jaxpr.jaxpr)
+        assert {"scan", "scatter"} <= seen, seen
+        assert not bad, bad
 
 
 class TestCollaborativeEngine:
