@@ -12,7 +12,10 @@ Everything a cell is made of is found by name: the cell in
 The window drives ``MonitorSession.step`` in ``sync`` mode with the
 in-process transport: each step is one tick of every attached stream, its
 trigger points set from the mix's trigger pattern through the session's
-trigger policy.
+trigger policy.  A cell on ``chips`` > 1 devices opens the session on its
+own mesh path, ``SessionConfig(mesh="data:<chips>")``: the weights are
+made replicated on that mesh, and the session splits every per-stream
+buffer over it.
 The host clock is read around each step; the moment a step's trigger
 decision is on the host is the end of the program's own ``edge.trigger``
 span (``SessionConfig(trace=True)``, ``docs/observability.md``), which is
@@ -189,8 +192,6 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     sz.update(overrides.get("config", {}))
     traffic.update(overrides.get("traffic", {}))
 
-    if cell["chips"] != 1:
-        raise BenchError(f"{workload}: the harness drives one chip")
     devs = find_devices(cell["chips"], allow_cpu)
     import jax
     peaks = peaks_for(devs[0].device_kind) if not allow_cpu else None
@@ -212,8 +213,15 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     import weights
     shapes = jax.eval_shape(lambda k: deco.init_collab_lm(k, cfg),
                             jax.random.PRNGKey(0))
+    mesh, replicated = None, None
+    if len(devs) > 1:                        # the session's own mesh path
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec
+        mesh = f"data:{len(devs)}"
+        replicated = NamedSharding(Mesh(np.asarray(devs), ("data",)),
+                                   PartitionSpec())
     params = weights.make(shapes, loadgen.jax_seed(seed, loadgen.WEIGHTS),
-                          t_init=sz["monitor"]["t_init"])
+                          t_init=sz["monitor"]["t_init"],
+                          out_shardings=replicated)
     import check
     reference = check.Reference(sz, mod)
     policy = scripted_policy()
@@ -223,7 +231,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     session = MonitorSession.open(
         params, served, batch=B, max_len=L,
         config=SessionConfig(mode="sync", policy=policy, trace=True,
-                             trace_capacity=16))
+                             trace_capacity=16, mesh=mesh))
     session.__enter__()
     eng, tracer = session.engine, session.tracer
     clock = tracer.clock
@@ -352,7 +360,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
                                "idle_gaps": summary["idle_gaps"]}
         ctx = {"summary": summary, "steps": steps, "lat": lat,
                "sz": sz, "mod": mod, "mon": sz["monitor"], "work": work,
-               "peaks": peaks, "traffic": traffic}
+               "peaks": peaks, "traffic": traffic, "chips": len(devs)}
         for m in bench["per_layer"]:
             if not applies(m, workload):
                 continue

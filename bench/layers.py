@@ -6,8 +6,13 @@ A reader (``bench/metrics/<metric>.py``) has one function,
 ``read(ctx) -> value | (value, extra) | None``; ``None`` when the run has
 nothing for it to read.  ``ctx`` holds the trace summary
 (``trace_reduce.reduce``), the window's steps, the host-clock latencies,
-the configuration's sizes and module and the peaks of the chip.  A cell
-runs on one chip.
+the configuration's sizes and module, the peaks of one chip and
+``chips``, the devices the cell runs on.
+
+A share of a peak is the least work of the whole batch over ``chips``
+times the chip's peaks: the same work whatever implements it, so a
+data-parallel program that reads the weights on every chip reads lower
+than the same batch on one chip.
 """
 from __future__ import annotations
 
@@ -47,9 +52,10 @@ def catchup_work(ctx):
 
 
 def roofline(ctx, programs, works):
-    """Least time of the work over its device time, in %, and which peak
-    bounds most of it: each step's least time on a chip is the larger of
-    its FLOPs over the peak rate and its bytes over the peak bandwidth."""
+    """Least time of the work over its device time (averaged over the
+    devices), in %, and which peak bounds most of it: each step's least
+    time is the larger of its FLOPs over the peak rate and its bytes over
+    the peak bandwidth, on ``chips`` chips."""
     t = device_s(ctx, programs)
     if not t or not ctx["steps"]:
         return None
@@ -57,7 +63,7 @@ def roofline(ctx, programs, works):
     for flops, nbytes in works:
         least, bound = least_seconds(flops, nbytes, ctx["peaks"])
         by[bound] += least
-    least = by["compute"] + by["memory"]
+    least = (by["compute"] + by["memory"]) / ctx["chips"]
     if not least:
         return None
     return 100.0 * least / t, {"bound": max(by, key=by.get)}
@@ -65,10 +71,10 @@ def roofline(ctx, programs, works):
 
 def step_mfu(ctx):
     """Useful model FLOPs of the traced window (edge and catch-up) over the
-    window's length times the chip's bf16 peak, in %."""
+    window's length times ``chips`` times the chip's bf16 peak, in %."""
     if not ctx["steps"]:
         return None
     flops = sum(f for f, _ in edge_work(ctx))
     flops += sum(f for f, _ in catchup_work(ctx))
     w = ctx["summary"]["window_s"]
-    return 100.0 * flops / (w * ctx["peaks"]["flops_bf16"])
+    return 100.0 * flops / (w * ctx["chips"] * ctx["peaks"]["flops_bf16"])

@@ -11,7 +11,8 @@ thread, inside ``bench.window``, each ``edge.catchup`` carrying
 ``replayed`` (backlog tokens shipped), ``rounds`` and ``rows`` as event
 stats.
 
-The counts come from the device.  The replay loop is the outermost
+The counts come from the devices, every ``/device:TPU:<n>`` plane of
+the trace.  The replay loop is the outermost
 ``%while`` operation inside each run of ``_catchup_impl`` (its body is the
 ``catchup.round`` named scope).  Its rounds are its body's iterations:
 each operation directly in the body (in no loop or branch inside it) runs
@@ -20,10 +21,16 @@ rows are the rows of the round's hidden state: the leading size of the
 two-dimensional ``[rows, hidden_size]`` operations directly in the body
 (the token embedding, the carried last hidden state), the most common;
 the layers' operations, nested deeper, hold weight slices such as
-``[1, hidden_size]``.  The ``rounds`` and ``rows`` the program reports on each
-``edge.catchup`` span are a cross-check only: a call where they differ
-from the device's, or a window where calls and spans do not pair one to
-one, reads ``None``, so no report of the program's can move a number here.
+``[1, hidden_size]``.  On a mesh (``data:<n>``) the catch-up is one SPMD
+program: each device runs the loop over its own block of rows, so a
+call's rows are the sum over devices, and its rounds are each device's
+loop count, which the devices have to agree on.  The ``rounds`` and
+``rows`` the program reports on each ``edge.catchup`` span are a
+cross-check only: a call where they differ from the devices', where the
+devices disagree on the rounds, or a window where calls and spans do not
+pair one to one on every device, reads ``None``, so no report of the
+program's can move a number here.  Device times and idle splits are
+averaged over the devices; on one device they are that device's.
 
 A trace with no ``edge.catchup`` span, or a catch-up with no loop, has
 nothing here to read: every function returns ``None``.  The xplane is
@@ -133,48 +140,13 @@ def _iterations(direct: Counter) -> int:
     return min(freq, key=lambda n: (-freq[n], n))
 
 
-def reduce(data) -> dict | None:
-    """``None`` where the trace has no ``edge.catchup`` span; otherwise
-
-    ``steps``: ``session.step`` spans in the window; ``catchups``: the
-    args of each ``edge.catchup`` span; ``calls``: per run of
-    ``_catchup_impl`` with a loop, ``rounds`` (the loop's iterations) and
-    ``shapes`` (``_loops``); ``catchup_s``, ``loop_s``, ``runs``: the
-    first device's seconds in ``_catchup_impl`` and in its outermost
-    ``%while``, and the program's runs; ``loop_ops``, ``fixed_ops``: the
-    nanoseconds of its operations inside and outside that loop by
-    ``(program id, instruction)``; ``program_ids``: the ids of
-    ``_catchup_impl`` in the module names; ``idle_s``: the window's idle
-    seconds on that device; ``idle_by_span``: idle seconds by the
-    innermost of ``session.step`` and the program's spans open through
-    them (``trace_reduce.timeline``)."""
-    window, spans, catchups = None, [], []
-    device = None
-    for plane in data.planes:
-        if plane.name.startswith("/host:CPU"):
-            for line in plane.lines:
-                evs = list(line.events)
-                if not any(ev.name == trace_reduce.WINDOW for ev in evs):
-                    continue                      # not the Python thread
-                for ev in evs:
-                    s, e = ev.start_ns, ev.start_ns + ev.duration_ns
-                    if ev.name == trace_reduce.WINDOW:
-                        window = (s, e)
-                    elif e > s:
-                        spans.append((ev.name, s, e))
-                        if ev.name == "edge.catchup":
-                            catchups.append((s, _stats(ev)))
-        elif device is None and re.match(r"^/device:TPU:\d+$", plane.name):
-            device = {ln.name: ln for ln in plane.lines}
-    if window is None or device is None or not catchups \
-            or "XLA Modules" not in device or "XLA Ops" not in device:
-        return None
-    lo, hi = window
-    catchups = [a for s, a in catchups if lo <= s < hi]
-    steps = sum(1 for n, s, _ in spans if n == STEP and lo <= s < hi)
-
+def _device(lines, lo, hi, segs) -> dict:
+    """One device's part of ``reduce``, inside the window ``[lo, hi)``:
+    its runs of ``_catchup_impl``, their loops, their operations' time
+    inside and outside the loops, and its idle seconds, in all and by the
+    host's segment ``segs`` (``trace_reduce.timeline``) they fall in."""
     runs, ids = [], set()
-    for ev in device["XLA Modules"].events:
+    for ev in lines["XLA Modules"].events:
         s, e = ev.start_ns, ev.start_ns + ev.duration_ns
         if trace_reduce.program_name(ev.name) == CATCHUP and e > lo and s < hi:
             pid = ev.name[ev.name.rfind("(") + 1:-1]
@@ -182,7 +154,7 @@ def reduce(data) -> dict | None:
             ids.add(pid)
     runs.sort()
     ops, short = [], {}
-    for ev in device["XLA Ops"].events:
+    for ev in lines["XLA Ops"].events:
         s, e = ev.start_ns, ev.start_ns + ev.duration_ns
         if e <= lo or s >= hi:
             continue
@@ -199,7 +171,6 @@ def reduce(data) -> dict | None:
             idle.append((prev, s))
         prev = max(prev, e)
     by = {}
-    segs = trace_reduce.timeline(spans, ours=(STEP,) + PROGRAM)
     seg_starts = [sg[0] for sg in segs]
     for a, b in idle:
         i = max(bisect.bisect_right(seg_starts, a) - 1, 0)
@@ -210,15 +181,83 @@ def reduce(data) -> dict | None:
                 by[mine] = by.get(mine, 0.0) + (e - s) * 1e-9
             i += 1
     return {
-        "steps": steps, "catchups": catchups,
         "calls": [{"rounds": _iterations(d), "shapes": sh}
                   for _, _, d, sh in loops],
         "catchup_s": sum(e - s for s, e, _ in runs) * 1e-9,
         "loop_s": sum(e - s for s, e, _, _ in loops) * 1e-9,
         "loop_ops": loop_ops, "fixed_ops": fixed_ops,
-        "runs": len(runs), "program_ids": sorted(ids),
+        "runs": len(runs), "program_ids": ids,
         "idle_s": sum(e - s for s, e in idle) * 1e-9,
         "idle_by_span": by,
+    }
+
+
+def _mean(dicts) -> dict:
+    """Key by key, the mean of ``dicts`` (a missing key counts 0)."""
+    keys = {k: None for d in dicts for k in d}
+    return {k: sum(d.get(k, 0) for d in dicts) / len(dicts) for k in keys}
+
+
+def reduce(data) -> dict | None:
+    """``None`` where the trace has no ``edge.catchup`` span; otherwise
+
+    ``steps``: ``session.step`` spans in the window; ``catchups``: the
+    args of each ``edge.catchup`` span; ``devices``: per device, in the
+    order of their ids, ``calls`` (per run of ``_catchup_impl`` with a
+    loop, ``rounds``, the loop's iterations, and ``shapes``, ``_loops``)
+    and ``runs`` (the program's runs); ``calls``, ``runs``: the first
+    device's; ``catchup_s``, ``loop_s``: the seconds in
+    ``_catchup_impl`` and in its outermost ``%while``; ``loop_ops``,
+    ``fixed_ops``: the nanoseconds of its operations inside and outside
+    that loop by ``(program id, instruction)``; ``idle_s``: the window's
+    idle seconds; ``idle_by_span``: idle seconds by the innermost of
+    ``session.step`` and the program's spans open through them
+    (``trace_reduce.timeline``): each of these a mean over the devices;
+    ``program_ids``: the ids of ``_catchup_impl`` in the module names."""
+    window, spans, catchups = None, [], []
+    devices = []
+    for plane in data.planes:
+        if plane.name.startswith("/host:CPU"):
+            for line in plane.lines:
+                evs = list(line.events)
+                if not any(ev.name == trace_reduce.WINDOW for ev in evs):
+                    continue                      # not the Python thread
+                for ev in evs:
+                    s, e = ev.start_ns, ev.start_ns + ev.duration_ns
+                    if ev.name == trace_reduce.WINDOW:
+                        window = (s, e)
+                    elif e > s:
+                        spans.append((ev.name, s, e))
+                        if ev.name == "edge.catchup":
+                            catchups.append((s, _stats(ev)))
+        else:
+            m = re.match(r"^/device:TPU:(\d+)$", plane.name)
+            if m:
+                devices.append((int(m.group(1)),
+                                {ln.name: ln for ln in plane.lines}))
+    if window is None or not devices or not catchups or any(
+            "XLA Modules" not in d or "XLA Ops" not in d
+            for _, d in devices):
+        return None
+    lo, hi = window
+    catchups = [a for s, a in catchups if lo <= s < hi]
+    steps = sum(1 for n, s, _ in spans if n == STEP and lo <= s < hi)
+    segs = trace_reduce.timeline(spans, ours=(STEP,) + PROGRAM)
+    per = [_device(lines, lo, hi, segs)
+           for _, lines in sorted(devices, key=lambda d: d[0])]
+    nd = len(per)
+    mean = lambda key: sum(d[key] for d in per) / nd
+    return {
+        "steps": steps, "catchups": catchups,
+        "devices": [{"calls": d["calls"], "runs": d["runs"]} for d in per],
+        "calls": per[0]["calls"], "runs": per[0]["runs"],
+        "catchup_s": mean("catchup_s"), "loop_s": mean("loop_s"),
+        "loop_ops": _mean([d["loop_ops"] for d in per]),
+        "fixed_ops": _mean([d["fixed_ops"] for d in per]),
+        "program_ids": sorted(set().union(*(d["program_ids"]
+                                             for d in per))),
+        "idle_s": mean("idle_s"),
+        "idle_by_span": _mean([d["idle_by_span"] for d in per]),
     }
 
 
@@ -231,25 +270,36 @@ def rows(shapes: Counter, hidden: int) -> int:
 
 
 def counts(r, hidden: int) -> dict | None:
-    """The window's catch-up counts, from the device: ``calls``,
-    ``rounds`` (sum of each call's loop iterations), ``rows_decoded``
-    (sum of rounds x rows) and ``replayed`` (the backlog tokens the
-    ``edge.catchup`` spans report).  ``None`` where there is no call, a
-    run has no loop, or calls and spans do not agree call by call on the
-    rounds and rows."""
-    if r is None or not r["calls"] or len(r["calls"]) != r["runs"] \
-            or len(r["calls"]) != len(r["catchups"]):
+    """The window's catch-up counts, from the devices: ``calls``,
+    ``rounds`` (sum of each call's loop iterations, which every device
+    ran alike), ``rows_decoded`` (sum of rounds x rows, a call's rows
+    summed over the devices), ``replayed`` (the backlog tokens the
+    ``edge.catchup`` spans report), ``devices``, and ``by_call``: each
+    call's ``rounds`` and ``rows`` per device.  ``None`` where there is no
+    call, a device's runs and calls do not pair one to one with the
+    spans, the devices disagree on a call's rounds, or a call's rounds and
+    rows disagree with its span's."""
+    if r is None or not r["catchups"]:
+        return None
+    n = len(r["catchups"])
+    devs = r["devices"]
+    if any(len(d["calls"]) != n or d["runs"] != n for d in devs):
         return None
     rounds = decoded = 0
-    for call, span in zip(r["calls"], r["catchups"]):
-        n, b = call["rounds"], rows(call["shapes"], hidden)
-        if n != span.get("rounds") or b != span.get("rows"):
+    by_call = []
+    for i, span in enumerate(r["catchups"]):
+        calls = [d["calls"][i] for d in devs]
+        k = calls[0]["rounds"]
+        b = [rows(c["shapes"], hidden) for c in calls]
+        if any(c["rounds"] != k for c in calls) \
+                or k != span.get("rounds") or sum(b) != span.get("rows"):
             return None
-        rounds += n
-        decoded += n * b
-    return {"calls": len(r["calls"]), "rounds": rounds,
-            "rows_decoded": decoded,
-            "replayed": sum(a["replayed"] for a in r["catchups"])}
+        rounds += k
+        decoded += k * sum(b)
+        by_call.append({"rounds": k, "rows": b})
+    return {"calls": n, "rounds": rounds, "rows_decoded": decoded,
+            "replayed": sum(a["replayed"] for a in r["catchups"]),
+            "devices": len(devs), "by_call": by_call}
 
 
 def ms_per_round(r, hidden: int) -> float | None:
@@ -270,6 +320,42 @@ def useful_row_share(r, hidden: int) -> float | None:
     c = counts(r, hidden)
     return 100.0 * c["replayed"] / c["rows_decoded"] \
         if c and c["rows_decoded"] else None
+
+
+def shard_idle_rounds(r, hidden: int, steps) -> tuple | None:
+    """The share of the rounds a device's replay loop ran in which none of
+    its own rows replayed a token, in %: sum(R - r_d) / sum(R) over the
+    window's calls and the devices, R the rounds the device's loop ran
+    and r_d the longest backlog among the rows it holds, read from the
+    window's ``steps`` (each step's ``replays``, ``(row, first, last)``)
+    with the rows split as ``P("data")`` splits them, in contiguous
+    blocks of B / devices.  Since every device runs R rounds, the sum does
+    not depend on which device holds which block.  Extra: the mean
+    ``rounds`` a device ran a call and the mean ``needed`` by its own
+    rows.  ``None`` where the counts read nothing, where the steps with a
+    replay do not pair one to one with the calls, or where a call's
+    rounds or a device's rows are not what the replays and the split
+    say."""
+    c = counts(r, hidden)
+    calls = [s for s in steps if s["replays"]]
+    if not c or len(calls) != c["calls"]:
+        return None
+    nd = c["devices"]
+    ran = needed = 0
+    for call, step in zip(c["by_call"], calls):
+        block = step["n"] // nd
+        need = [0] * nd
+        for row, first, last in step["replays"]:
+            need[row // block] = max(need[row // block], last - first + 1)
+        if max(need) != call["rounds"] or call["rows"] != [block] * nd:
+            return None
+        ran += nd * call["rounds"]
+        needed += sum(need)
+    if not ran:
+        return None
+    return 100.0 * (ran - needed) / ran, {
+        "rounds": ran / (nd * c["calls"]),
+        "needed": needed / (nd * c["calls"])}
 
 
 def idle_ms_per_step(r, spans) -> tuple | None:

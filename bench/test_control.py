@@ -11,7 +11,8 @@ from conftest import tiny_overrides
 
 @pytest.mark.parametrize("workload,config", [
     ("granite-8b.storm", "granite-8b-chip"),
-    ("zamba2-7b.storm", "zamba2-7b-chip")])
+    ("zamba2-7b.storm", "zamba2-7b-chip"),
+    ("granite-8b.storm.data4", "granite-8b-chip")])
 @pytest.mark.parametrize("seed", [3, 2**31 + 1, 5_000_000_019])
 def test_control_fails(workload, config, seed):
     got = control.readings(workload, seed, 24, allow_cpu=True,

@@ -21,7 +21,7 @@ WINDOW = "bench.window"
 # control flow whose event spans the events of the operations inside it
 CONTAINERS = ("%while", "%conditional", "%call")
 # the benchmark's own host spans (harness.py), by which idle gaps are told
-SPANS = ("session.step", "server.catchup", "loadgen.wait", "pool.turnover")
+SPANS = ("session.step", "loadgen.wait", "pool.turnover")
 _SUFFIX = re.compile(r"\(\d+\)$")
 
 

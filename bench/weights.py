@@ -58,9 +58,11 @@ def _leaf(key, path: str, shape, dtype, t_init: float):
     return v.astype(dtype)
 
 
-def make(shapes, seed: int, *, t_init: float):
+def make(shapes, seed: int, *, t_init: float, out_shardings=None):
     """Fill the tree ``shapes`` (ShapeDtypeStructs) from ``seed`` in one
-    jitted call on the device."""
+    jitted call on the device; with ``out_shardings`` (a replicated
+    ``NamedSharding`` on a mesh) every device of the mesh makes the same
+    values in that call, with no copy made first on one device."""
     flat, treedef = jax.tree_util.tree_flatten_with_path(shapes)
     paths = [_path(kp) for kp, _ in flat]
 
@@ -70,4 +72,7 @@ def make(shapes, seed: int, *, t_init: float):
             _leaf(keys[i], paths[i], s.shape, s.dtype, t_init)
             for i, (_, s) in enumerate(flat)])
 
-    return jax.jit(init)(jax.random.PRNGKey(seed))
+    if out_shardings is None:
+        return jax.jit(init)(jax.random.PRNGKey(seed))
+    return jax.jit(init, out_shardings=out_shardings)(
+        jax.random.PRNGKey(seed))
